@@ -80,7 +80,7 @@ def _max_dev(a, b) -> float:
 
 def _min_bytes_config(gc, grid, app: str) -> tcost.Scored:
     """Deterministic analytic choice: least modeled bytes, key tie-break."""
-    ranked = tcost.rank(gc, grid, app=app)
+    ranked = tcost.rank(gc, grid, app=app, hw=HW.profile("cpu-interpret"))
     return min(ranked, key=lambda s: (s.model_bytes,
                                       tcost.config_key(s.config)))
 

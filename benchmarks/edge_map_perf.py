@@ -17,8 +17,8 @@ Per (dataset, ordering) cell:
   * **HBM bytes per iteration**: the flat path measured by XLA
     ``cost_analysis()`` (plus an analytic pass-model cross-check), the fused
     path from the kernels' ``pl.CostEstimate`` accounting
-    (``fused_edge_map_bytes``) — tile planes + VMEM-resident property vector,
-    one pass, no O(E) intermediates.
+    (``fused_edge_map_bytes``) — the XLA gather of ``x[idx]`` into
+    slot-sized value tiles, then one kernel pass over them and the planes.
 
 Per dataset (DBG ordering), every app runs on BOTH backends: per-iteration
 time, iteration counts, and max result deviation (min/max reductions are

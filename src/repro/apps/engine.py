@@ -282,7 +282,7 @@ class FusedEdgeMaps:
     in_tiles: Tuple  # Tuple[EllTileGroup, ...]
     row_tile: int
     width_tile: int
-    interpret: bool
+    interpret: Optional[bool]
 
     def _kernel_kw(self):
         return dict(row_tile=self.row_tile, width_tile=self.width_tile,
@@ -339,7 +339,7 @@ class EllBackend(_Delegate, FusedEdgeMaps):
     in_tiles: Tuple  # Tuple[EllTileGroup, ...]
     row_tile: int = 64
     width_tile: int = 128
-    interpret: bool = True
+    interpret: Optional[bool] = None
 
     def tree_flatten(self):
         return ((self.ga, self.in_tiles),
@@ -363,7 +363,7 @@ def _build_flat(g: csr.Graph, **_):
 
 
 def _build_ell(g: csr.Graph, *, row_tile: int = 64, width_tile: int = 128,
-               interpret: bool = True):
+               interpret: Optional[bool] = None):
     from ..core.reorder import dbg_spec
     from ..kernels.edge_map.ops import ell_tiles
 
@@ -376,7 +376,7 @@ def _build_ell(g: csr.Graph, *, row_tile: int = 64, width_tile: int = 128,
 
 
 def _build_packed(g: csr.Graph, *, row_tile: int = 64, width_tile: int = 128,
-                  interpret: bool = True, slot_align: int = 16,
+                  interpret: Optional[bool] = None, slot_align: int = 16,
                   hot_groups: int = 0):
     from ..pack.engine import packed_backend
     from ..pack.layout import pack_graph

@@ -6,11 +6,9 @@ pure owner-partitioning (``"partition"``).
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
-import numpy as np
 
 from ..dist import graph as dist_graph
 from ..graph import csr
@@ -19,19 +17,12 @@ from .engine import GraphArrays, to_arrays
 __all__ = ["pagerank_dist", "make_graph_mesh"]
 
 
-@functools.lru_cache(maxsize=None)
-def _graph_mesh(n: int):
-    return jax.sharding.Mesh(np.array(jax.devices()[:n]), (dist_graph.AXIS,))
-
-
 def make_graph_mesh(n_shards: Optional[int] = None):
-    """1D ``("graph",)`` mesh over the first ``n_shards`` devices.
-
-    Cached per size so repeat ``pagerank_dist`` calls hit the compiled-
-    executable cache (which is mesh-identity keyed)."""
+    """1D ``("graph",)`` mesh over the first ``n_shards`` devices
+    (:func:`repro.dist.graph.graph_mesh`, cached per size)."""
     devs = jax.devices()
     n = len(devs) if n_shards is None else min(n_shards, len(devs))
-    return _graph_mesh(n)
+    return dist_graph.graph_mesh(n)
 
 
 def pagerank_dist(

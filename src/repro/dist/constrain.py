@@ -42,16 +42,9 @@ _CTX = _Ctx()
 
 
 def _ambient_mesh_shape() -> Dict[str, int]:
-    """Axis sizes of the mesh context manager we are tracing under, if any."""
-    try:
-        from jax._src import mesh as mesh_lib
-
-        pm = mesh_lib.thread_resources.env.physical_mesh
-        if not pm.empty:
-            return dict(pm.shape)
-    except Exception:
-        pass
-    return {}
+    """Axis sizes of the mesh set with ``jax.set_mesh``, if any."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return {} if mesh.empty else dict(mesh.shape)
 
 
 def _mesh_sizes() -> Dict[str, int]:
@@ -64,7 +57,7 @@ def activation_sharding(axes: Sequence[str], sizes: Optional[Dict[str, int]] = N
 
     ``axes``: live mesh axis names (usually ``mesh.axis_names``).
     ``sizes``: optional ``{axis: size}`` for divisibility checks; defaults to
-    the ambient mesh entered with ``with mesh:``.
+    the ambient mesh set with ``jax.set_mesh``.
     """
     prev = (_CTX.axes, _CTX.sizes)
     _CTX.axes = tuple(axes)

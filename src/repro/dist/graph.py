@@ -48,12 +48,13 @@ does the full re-shard it would have done every time before).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from ..apps.engine import GraphArrays
@@ -67,11 +68,23 @@ from ..kernels.edge_map.ops import (_scatter_combine, _tile_of,
                                     ell_tiles_sharded)
 
 __all__ = ["ShardedGraphArrays", "ShardDeltaSegment", "shard_graph",
+           "graph_mesh", "place_shards",
            "edge_map_pull_sharded", "edge_map_push_sharded",
            "edge_map_bytes_sharded", "pagerank_sharded", "apply_remap",
            "RemapOverflow", "HaloOverflow"]
 
 AXIS = "graph"
+
+
+@functools.lru_cache(maxsize=None)
+def graph_mesh(n: int) -> Mesh:
+    """1D ``(AXIS,)`` mesh over the first ``n`` devices: the mesh every
+    sharded entry builds and the one :func:`place_shards` puts the shard
+    state on.  Cached per size, so repeat solves hit the compiled-executable
+    cache (which is mesh-identity keyed).  The axis is ``Auto``: outside the
+    ``shard_map`` bodies XLA propagates the sharding itself."""
+    return Mesh(np.array(jax.devices()[:n]), (AXIS,),
+                axis_types=(AxisType.Auto,))
 
 #: backends the sharded engine implements (a subset of apps.engine.BACKENDS)
 SHARDED_BACKENDS = ("flat", "ell")
@@ -152,9 +165,9 @@ class ShardedGraphArrays:
     weighted: bool = False
     row_tile: int = 64
     width_tile: int = 128
-    # Pallas interpret mode for the fused per-shard kernels (True = the
-    # CPU-validated path, same default and meaning as apps.engine.EllBackend)
-    interpret: bool = True
+    # Pallas interpret mode for the fused per-shard kernels; None derives it
+    # from the platform (repro.kernels.mode), as apps.engine.EllBackend does
+    interpret: Optional[bool] = None
     pull_tiles: Optional[Tuple] = None  # stacked EllTileGroups (slots → table)
     push_tiles: Optional[Tuple] = None  # stacked EllTileGroups (dst → local)
     # streaming delta segment (dist.stream): per-shard edge-delta buffers +
@@ -174,6 +187,46 @@ class ShardedGraphArrays:
     def table_len(self) -> int:
         """Per-shard gather-table length: [local | hot | halo]."""
         return self.v_blk + self.hot_cap + self.n_shards * self.halo_max
+
+
+#: device-array fields of a ShardedGraphArrays: the (D, ...) per-shard
+#: stacks (incl. tile groups and the delta segment) and the replicated rest
+_SHARD_FIELDS = ("in_slot", "in_dst_local", "in_w", "in_mask", "send_idx",
+                 "out_src_local", "out_dst", "out_w", "out_mask",
+                 "pull_tiles", "push_tiles", "delta")
+_REPLICA_FIELDS = ("hot_ids", "in_deg", "out_deg")
+_ARRAY_FIELDS = _SHARD_FIELDS + _REPLICA_FIELDS
+
+
+def _sg_arrays(sg: ShardedGraphArrays) -> dict:
+    """The device arrays of ``sg`` as one pytree — what jitted solves take
+    as ARGUMENTS (closed-over arrays would be baked into the program as
+    constants)."""
+    return {f: getattr(sg, f) for f in _ARRAY_FIELDS}
+
+
+def place_shards(sg: ShardedGraphArrays) -> ShardedGraphArrays:
+    """Put shard ``i``'s slice of every per-shard stack on device ``i`` of
+    :func:`graph_mesh` (``NamedSharding(mesh, P(AXIS))``) and replicate the
+    O(V) vectors, so no device holds another's shard.  Host (numpy) arrays
+    go straight to their devices; device arrays already placed are left
+    alone.  A layout built for more shards than this process has devices
+    (host-side layout statistics) stays on the default device."""
+    if sg.n_shards > len(jax.devices()):
+        mesh = None
+    else:
+        mesh = graph_mesh(sg.n_shards)
+
+    def put(tree, spec):
+        if mesh is None:
+            return jax.tree_util.tree_map(jnp.asarray, tree)
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree_util.tree_map(
+            lambda x: jax.device_put(x, sharding), tree)
+
+    return dataclasses.replace(
+        sg, **{f: put(getattr(sg, f), P(AXIS)) for f in _SHARD_FIELDS},
+        **{f: put(getattr(sg, f), P()) for f in _REPLICA_FIELDS})
 
 
 def _hot_mask(out_deg: np.ndarray, policy: str,
@@ -235,7 +288,7 @@ def shard_graph(ga: GraphArrays, n_shards: int, *,
                 backend: str = "flat",
                 row_tile: int = 64,
                 width_tile: int = 128,
-                interpret: bool = True,
+                interpret: Optional[bool] = None,
                 hot_override: Optional[np.ndarray] = None,
                 remap_headroom: float = 0.25,
                 track_remap: Optional[bool] = None,
@@ -472,22 +525,20 @@ def shard_graph(ga: GraphArrays, n_shards: int, *,
                 "push_tile_w": (None if push_tiles is None or not weighted
                                 else [np.array(t.w) for t in push_tiles]),
             }
-    return ShardedGraphArrays(
+    return place_shards(ShardedGraphArrays(
         n_shards=d, num_vertices=v, v_blk=v_blk, halo_max=halo_cap,
         policy=policy,
-        in_slot=jnp.asarray(in_slot), in_dst_local=jnp.asarray(in_dst_local),
-        in_w=jnp.asarray(in_w_p), in_mask=jnp.asarray(in_mask),
-        send_idx=jnp.asarray(send_idx), hot_ids=jnp.asarray(hot_ids_pad),
-        out_src_local=jnp.asarray(out_src_local),
-        out_dst=jnp.asarray(out_dst_p), out_w=jnp.asarray(out_w_p),
-        out_mask=jnp.asarray(out_mask),
-        in_deg=jnp.asarray(ga.in_deg), out_deg=jnp.asarray(ga.out_deg),
+        in_slot=in_slot, in_dst_local=in_dst_local, in_w=in_w_p,
+        in_mask=in_mask, send_idx=send_idx.copy(), hot_ids=hot_ids_pad,
+        out_src_local=out_src_local, out_dst=out_dst_p, out_w=out_w_p,
+        out_mask=out_mask,
+        in_deg=np.asarray(ga.in_deg), out_deg=np.asarray(ga.out_deg),
         backend=backend, hot_cap=hot_cap, hot_group_count=hgc,
         weighted=weighted, row_tile=row_tile, width_tile=width_tile,
         interpret=interpret,
         pull_tiles=pull_tiles, push_tiles=push_tiles,
         stats=stats, host=host,
-    )
+    ))
 
 
 def _check_backend(backend: str) -> str:
@@ -610,9 +661,10 @@ def edge_map_pull_sharded(sg: ShardedGraphArrays, prop: jnp.ndarray, mesh, *,
             return out[None]
 
         a = P(AXIS)
-        fn = shard_map(ranked, mesh=mesh,
-                       in_specs=(a, P(), a, a, a, a, a) + (a,) * len(dargs),
-                       out_specs=a, check_rep=False)
+        fn = jax.shard_map(
+            ranked, mesh=mesh,
+            in_specs=(a, P(), a, a, a, a, a) + (a,) * len(dargs),
+            out_specs=a, check_vma=False)
         with obs_trace.span("dist.edge_map_pull", cat="dist",
                             backend=backend, shards=d, reduce=reduce):
             out = fn(prop_blocks, hot_tab, sg.send_idx, sg.in_slot,
@@ -650,9 +702,10 @@ def edge_map_pull_sharded(sg: ShardedGraphArrays, prop: jnp.ndarray, mesh, *,
         return out[None]
 
     a = P(AXIS)
-    fn = shard_map(ranked_ell, mesh=mesh,
-                   in_specs=(a, P(), a) + (a,) * (n_base + len(dtile_args)),
-                   out_specs=a, check_rep=False)
+    fn = jax.shard_map(
+        ranked_ell, mesh=mesh,
+        in_specs=(a, P(), a) + (a,) * (n_base + len(dtile_args)),
+        out_specs=a, check_vma=False)
     with obs_trace.span("dist.edge_map_pull", cat="dist",
                         backend=backend, shards=d, reduce=reduce):
         out = fn(prop_blocks, hot_tab, sg.send_idx, *tile_args, *dtile_args)
@@ -726,9 +779,9 @@ def edge_map_push_sharded(sg: ShardedGraphArrays, prop: jnp.ndarray, mesh, *,
             return collect(partial)[None]
 
         a = P(AXIS)
-        fn = shard_map(ranked, mesh=mesh,
-                       in_specs=(a, a, a, a, a) + (a,) * len(dargs),
-                       out_specs=a, check_rep=False)
+        fn = jax.shard_map(ranked, mesh=mesh,
+                           in_specs=(a, a, a, a, a) + (a,) * len(dargs),
+                           out_specs=a, check_vma=False)
         with obs_trace.span("dist.edge_map_push", cat="dist",
                             backend=backend, shards=d, reduce=reduce):
             out = fn(prop_blocks, sg.out_src_local, sg.out_dst, sg.out_w,
@@ -761,9 +814,9 @@ def edge_map_push_sharded(sg: ShardedGraphArrays, prop: jnp.ndarray, mesh, *,
             return collect(partial)[None]
 
         a = P(AXIS)
-        fn = shard_map(ranked_ell, mesh=mesh,
-                       in_specs=(a,) + (a,) * (n_base + len(dtile_args)),
-                       out_specs=a, check_rep=False)
+        fn = jax.shard_map(ranked_ell, mesh=mesh,
+                           in_specs=(a,) + (a,) * (n_base + len(dtile_args)),
+                           out_specs=a, check_vma=False)
         with obs_trace.span("dist.edge_map_push", cat="dist",
                             backend=backend, shards=d, reduce=reduce):
             out = fn(prop_blocks, *tile_args, *dtile_args)
@@ -824,7 +877,7 @@ def edge_map_bytes_sharded(sg: ShardedGraphArrays, *, mode: str = "pull",
     for t in tuple(tiles) + tuple(dtiles):
         r_pad, w_pad = int(t.idx.shape[1]), int(t.idx.shape[2])
         total += edge_map_tile_bytes(
-            r_pad, w_pad, table,
+            r_pad, w_pad,
             weighted=use_weights and t.w is not None,
             frontier=False, alive=t.alive is not None, init=False,
             idx_itemsize=t.idx.dtype.itemsize)
@@ -1040,14 +1093,14 @@ def apply_remap(sg: ShardedGraphArrays, delta) -> ShardedGraphArrays:
     stats["halo_slots"] = int(host["halo_slots"])
     stats["n_hot"] = int(np.sum(hot_pos >= 0))
     stats["hot_frac"] = stats["n_hot"] / max(1, v)
-    return dataclasses.replace(
+    return place_shards(dataclasses.replace(
         sg,
         in_slot=in_slot,
-        send_idx=jnp.asarray(send_master),
-        hot_ids=jnp.asarray(host["hot_ids"]),
+        send_idx=send_master.copy(),
+        hot_ids=host["hot_ids"].copy(),
         pull_tiles=pull_tiles,
         stats=stats,
-    )
+    ))
 
 
 # ---------------------------------------------------------------------------
@@ -1073,11 +1126,14 @@ def pagerank_sharded(sg: ShardedGraphArrays, mesh, *, damping: float = 0.85,
     if key not in _PR_CACHE:
         while len(_PR_CACHE) >= _PR_CACHE_MAX:
             _PR_CACHE.pop(next(iter(_PR_CACHE)))
-        v = sg.num_vertices
-        out_deg = jnp.maximum(1, sg.out_deg).astype(jnp.float32)
-        dangling = (sg.out_deg == 0).astype(jnp.float32)
+        sg0 = sg
 
-        def run():
+        def run(arrs):
+            sgt = dataclasses.replace(sg0, **arrs)
+            v = sg0.num_vertices
+            out_deg = jnp.maximum(1, sgt.out_deg).astype(jnp.float32)
+            dangling = (sgt.out_deg == 0).astype(jnp.float32)
+
             def cond(state):
                 _, it, err = state
                 return jnp.logical_and(it < max_iters, err > tol)
@@ -1085,7 +1141,7 @@ def pagerank_sharded(sg: ShardedGraphArrays, mesh, *, damping: float = 0.85,
             def body(state):
                 rank, it, _ = state
                 contrib = rank / out_deg
-                pulled = edge_map_pull_sharded(sg, contrib, mesh)
+                pulled = edge_map_pull_sharded(sgt, contrib, mesh)
                 dangling_mass = jnp.sum(rank * dangling) / v
                 new = (1.0 - damping) / v + damping * (pulled + dangling_mass)
                 err = jnp.sum(jnp.abs(new - rank))
@@ -1097,7 +1153,7 @@ def pagerank_sharded(sg: ShardedGraphArrays, mesh, *, damping: float = 0.85,
         _PR_CACHE[key] = jax.jit(run)
     with obs_trace.span("dist.pagerank", cat="dist", backend=sg.backend,
                         shards=sg.n_shards) as sp:
-        rank, iters, _ = jax.block_until_ready(_PR_CACHE[key]())
+        rank, iters, _ = jax.block_until_ready(_PR_CACHE[key](_sg_arrays(sg)))
         sp.add(iters=int(iters))
     hook = apps_engine.get_edge_map_hook()
     if hook is not None and hasattr(hook, "record_iters"):

@@ -13,7 +13,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 __all__ = ["pipeline_apply"]
@@ -58,7 +57,7 @@ def pipeline_apply(stage_fn, params, microbatches, mesh, axis: str = "pipe"):
         # only the last device filled its buffer; psum replicates the result
         return jax.lax.psum(out, axis)
 
-    fn = shard_map(ranked, mesh=mesh,
-                   in_specs=(P(axis), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(ranked, mesh=mesh,
+                       in_specs=(P(axis), P()), out_specs=P(),
+                       check_vma=False)
     return fn(params, microbatches)

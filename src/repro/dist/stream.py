@@ -49,7 +49,8 @@ from ..obs import flight as obs_flight
 from ..obs import trace as obs_trace
 from ..kernels.edge_map.ops import _pad_dim, coo_tiles_sharded
 from .graph import (HaloOverflow, ShardDeltaSegment, ShardedGraphArrays,
-                    _halo_slot, _key_index, edge_map_pull_sharded)
+                    _halo_slot, _key_index, _sg_arrays, edge_map_pull_sharded,
+                    place_shards)
 
 __all__ = ["apply_edge_delta", "sync_delta", "compact_shards",
            "pagerank_sharded_stream", "sssp_sharded_stream"]
@@ -256,7 +257,7 @@ def apply_edge_delta(sg: ShardedGraphArrays, result, *,
     repl: Dict[str, Any] = {}
     if int(host["halo_slots"]) != halo_before:
         # new halo members must ride the all_to_all: refresh the send table
-        repl["send_idx"] = jnp.asarray(host["send_idx"])
+        repl["send_idx"] = host["send_idx"].copy()
     if pull_mask:
         ii, pp = (np.array(x, np.int64) for x in zip(*pull_mask))
         repl["in_mask"] = sg.in_mask.at[ii, pp].set(False)
@@ -299,10 +300,13 @@ def sync_delta(sg: ShardedGraphArrays) -> ShardedGraphArrays:
 
     No-op unless the buffers changed since the last sync.  Cost is
     O(capacity), and capacity is bounded by the per-shard compaction
-    threshold — this is the "delta" in the batch path's O(delta)."""
+    threshold — this is the "delta" in the batch path's O(delta).  Every
+    per-shard array leaves on its own device (``graph.place_shards``), so
+    the in-place row patches of ingest and compaction never gather a
+    shard's state onto one device."""
     st = _stream_state(sg)
     if not st["delta_dirty"] and sg.delta is not None:
-        return sg
+        return place_shards(sg)
     d, v_blk = sg.n_shards, sg.v_blk
     c = max(st["caps"]["c"], _next_pow2(max(b["n"] for b in st["d"])))
     cp = max(st["caps"]["cp"], _next_pow2(max(b["n"] for b in st["p"])))
@@ -359,12 +363,10 @@ def sync_delta(sg: ShardedGraphArrays) -> ShardedGraphArrays:
                             int(push_tiles[0].idx.shape[2]))
 
     st["delta_dirty"] = False
-    return dataclasses.replace(sg, delta=ShardDeltaSegment(
-        slot=jnp.asarray(slot), dstl=jnp.asarray(dstl), w=jnp.asarray(w),
-        alive=jnp.asarray(alive), p_srcl=jnp.asarray(p_srcl),
-        p_dst=jnp.asarray(p_dst), p_w=jnp.asarray(p_w),
-        p_alive=jnp.asarray(p_alive),
-        pull_tiles=pull_tiles, push_tiles=push_tiles))
+    return place_shards(dataclasses.replace(sg, delta=ShardDeltaSegment(
+        slot=slot, dstl=dstl, w=w, alive=alive, p_srcl=p_srcl, p_dst=p_dst,
+        p_w=p_w, p_alive=p_alive,
+        pull_tiles=pull_tiles, push_tiles=push_tiles)))
 
 
 # ---------------------------------------------------------------------------
@@ -642,16 +644,8 @@ def compact_shards(sg: ShardedGraphArrays, *, threshold: float = 0.25,
 # static geometry — recompiles are logarithmic in the batch count
 # ---------------------------------------------------------------------------
 
-_ARRAY_FIELDS = ("in_slot", "in_dst_local", "in_w", "in_mask", "send_idx",
-                 "hot_ids", "out_src_local", "out_dst", "out_w", "out_mask",
-                 "in_deg", "out_deg", "pull_tiles", "push_tiles", "delta")
-
 _Q_CACHE: Dict[Tuple[Any, ...], Any] = {}
 _Q_CACHE_MAX = 64
-
-
-def _sg_arrays(sg: ShardedGraphArrays) -> dict:
-    return {f: getattr(sg, f) for f in _ARRAY_FIELDS}
 
 
 def _geom_key(sg: ShardedGraphArrays, mesh) -> Tuple[Any, ...]:

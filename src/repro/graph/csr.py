@@ -94,11 +94,27 @@ class Graph:
         return self.out_csr.degrees()
 
 
+def _stable_argsort(key: np.ndarray, upper: int) -> np.ndarray:
+    """``np.argsort(key, kind="stable")`` for integer keys in ``[0, upper)``,
+    as least-significant-digit passes over 16-bit digits: numpy's stable
+    sort is a radix sort at 16 bits, so this is ~2x faster on tens of
+    millions of keys, with the same order."""
+    order = None
+    shift = 0
+    while True:
+        digits = (key if order is None else key[order]) >> shift
+        step = np.argsort((digits & 0xFFFF).astype(np.uint16), kind="stable")
+        order = step if order is None else order[step]
+        shift += 16
+        if (max(1, upper) - 1) >> shift == 0:
+            return order
+
+
 def _build_one_direction(
     key: np.ndarray, other: np.ndarray, num_vertices: int, weights: Optional[np.ndarray]
 ) -> CSR:
     """Group ``other`` endpoints by ``key`` endpoint (stable) into CSR."""
-    order = np.argsort(key, kind="stable")
+    order = _stable_argsort(key, num_vertices)
     sorted_key = key[order]
     indices = other[order].astype(np.int32)
     counts = np.bincount(sorted_key, minlength=num_vertices)

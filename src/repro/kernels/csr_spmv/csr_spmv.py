@@ -23,9 +23,13 @@ DynamicGather on v4+); validated in interpret mode on CPU.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..mode import interpret_mode
 
 __all__ = ["ell_spmv_pallas"]
 
@@ -51,7 +55,7 @@ def ell_spmv_pallas(
     *,
     row_tile: int = 256,
     width_tile: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """y (R,) = rowsum(x[idx] * w). R % row_tile == 0, W % width_tile == 0
     (ops.py pads)."""
@@ -68,5 +72,5 @@ def ell_spmv_pallas(
         ],
         out_specs=pl.BlockSpec((row_tile,), lambda i, j: (i,)),  # y: per row tile
         out_shape=jax.ShapeDtypeStruct((r,), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, idx, w)

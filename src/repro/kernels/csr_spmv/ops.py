@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import List, Sequence
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -78,7 +78,7 @@ def ell_spmv(
     *,
     row_tile: int = 256,
     width_tile: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Single-group jit'd wrapper (shapes already tile-aligned)."""
     return ell_spmv_pallas(
@@ -93,7 +93,7 @@ def dbg_spmv(
     *,
     row_tile: int = 256,
     width_tile: int = 512,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Full pull-mode edge map: scatter per-group row sums back to vertex ids.
 

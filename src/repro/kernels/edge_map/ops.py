@@ -32,13 +32,16 @@ __all__ = [
     "refresh_alive",
     "fused_edge_map",
     "fused_edge_map_bytes",
+    "width_bins",
 ]
 
 
 class EllTileGroup(NamedTuple):
     """Device view of one degree-group's ELL tiles.
 
-    ``rows``  (R,)  int32 owning vertex ids (true, unpadded count)
+    ``rows``  (R,)  int32 owning vertex ids (true, unpadded count); a
+                    primary tile set (``ell_tiles``, the packed hot tables)
+                    keeps them ascending, which its combine declares
     ``idx``   (R_pad, W_pad) int32 neighbor ids (0 in padding lanes)
     ``deg``   (R_pad,) int32 true degrees (0 for padding rows)
     ``w``     optional (R_pad, W_pad) f32 additive weights
@@ -79,6 +82,22 @@ def _id_dtype(num_vertices: int):
     """Minimal-width storage for neighbor ids (the pack-subsystem idiom:
     uint16 slots halve the dominant idx-plane bytes at bench scales)."""
     return np.uint16 if num_vertices <= np.iinfo(np.uint16).max else np.int32
+
+
+def width_bins(boundaries: Sequence[int], max_degree: int) -> Tuple[int, ...]:
+    """DBG ``boundaries`` (descending) extended with doubling bins above the
+    hottest one, so no row is padded to more than twice its degree.
+
+    DBG's top group is unbounded: on a Graph500 scale-22 graph it spans
+    in-degrees 512 to ~100K, and one width class for all of it would pad
+    ~25K rows to the widest (gigabytes of idx plane).  The geometric bins
+    below it already bound padding at 2x; this continues them upward."""
+    out = [int(b) for b in boundaries]
+    top = max(1, out[0])
+    while top * 2 <= max_degree:
+        top *= 2
+        out.insert(0, top)
+    return tuple(out)
 
 
 def _slot_coords(degs: np.ndarray):
@@ -158,12 +177,12 @@ def ell_tiles(
     from ...core.reorder import _assign_groups
 
     deg_all = adj.degrees()
+    boundaries = width_bins(boundaries, int(deg_all.max(initial=0)))
     grp = _assign_groups(deg_all, boundaries)
     # bin by DBG group, then MERGE bins that land in the same padded width
     # class: the deg mask already handles intra-group variance, and one tile
-    # set per width class means the V-sized x/frontier vectors are fetched
-    # once per class instead of once per bin (several cold bins share the
-    # fine 8/16-lane widths).
+    # set per width class means one gather + kernel launch per class instead
+    # of one per bin (several cold bins share the fine 8/16-lane widths).
     by_width = {}
     for k in range(len(boundaries)):
         # zero-degree rows really are skipped (they take the reduction
@@ -182,6 +201,8 @@ def ell_tiles(
     for w_pad, parts in by_width.items():  # insertion order: hottest first
         rows = np.concatenate([p[0] for p in parts])
         degs = np.concatenate([p[1] for p in parts])
+        order = np.argsort(rows, kind="stable")  # ascending: sorted combine
+        rows, degs = rows[order], degs[order]
         r_pad = _pad_dim(rows.size, row_tile)
         idx, w, alive = _fill_planes(adj, rows, degs, r_pad, w_pad,
                                      alive_edges)
@@ -211,8 +232,10 @@ def ell_tiles_sharded(
 
     ``shard_edges[i] = (rows, cols, w|None)`` is shard *i*'s edge list in host
     numpy (rows = owning row ids in that shard's private row space, cols =
-    gather indices < ``id_upper``).  The returned groups carry a leading shard
-    dim on every plane — ``rows (D, R_pad)``, ``idx (D, R_pad, W_pad)``,
+    gather indices < ``id_upper``).  The returned groups are host (numpy)
+    planes, which the sharded engine puts shard by shard on their devices
+    (``dist.graph.place_shards``).  They carry a leading shard dim on every
+    plane — ``rows (D, R_pad)``, ``idx (D, R_pad, W_pad)``,
     ``deg (D, R_pad)``, optional ``w`` — because ``shard_map`` needs one
     static tile geometry per device: rows are binned by their (shard-local)
     degree into the shared geometric ``boundaries``, each bin's padded width
@@ -244,6 +267,7 @@ def ell_tiles_sharded(
     if boundaries is None:
         mean = max(1.0, float(pooled.mean()) if pooled.size else 1.0)
         boundaries = dbg_spec(mean).boundaries
+    boundaries = width_bins(boundaries, int(pooled.max(initial=0)))
     nb = len(boundaries)
     shard_bins = [_assign_groups(p[1], boundaries) for p in per]
     bin_wmax = np.zeros(nb, np.int64)
@@ -290,10 +314,8 @@ def ell_tiles_sharded(
                 positions[i][inp, 1] = row_rep
                 positions[i][inp, 2] = col
         groups.append(EllTileGroup(
-            rows=jnp.asarray(rws), idx=jnp.asarray(idx),
-            deg=jnp.asarray(deg),
-            w=None if wgt is None else jnp.asarray(wgt),
-            alive=(jnp.ones((d, r_pad, w_pad), jnp.int8)
+            rows=rws, idx=idx, deg=deg, w=wgt,
+            alive=(np.ones((d, r_pad, w_pad), np.int8)
                    if with_alive else None)))
     tiles = tuple(groups)
     if with_positions:
@@ -354,8 +376,8 @@ def coo_tiles_sharded(
 ) -> Tuple[EllTileGroup, ...]:
     """The delta-segment companion of :func:`ell_tiles_sharded`: D per-shard
     COO delta lists packed into ONE dst-grouped tile group with a leading
-    shard dim, so the stream delta buffer rides ``shard_map`` next to the
-    stacked base tiles.
+    shard dim (host planes, like the base packer's), so the stream delta
+    buffer rides ``shard_map`` next to the stacked base tiles.
 
     ``shard_edges[i] = (rows, cols, w|None)`` is shard *i*'s ALIVE delta
     edges (rows = destination ids in that shard's row space, cols = gather
@@ -397,9 +419,7 @@ def coo_tiles_sharded(
             wgt[i][row_rep, col] = ws
         deg[i, : urows.size] = degs
         rws[i, : urows.size] = urows.astype(np.int32)
-    return (EllTileGroup(
-        rows=jnp.asarray(rws), idx=jnp.asarray(idx), deg=jnp.asarray(deg),
-        w=None if wgt is None else jnp.asarray(wgt)),)
+    return (EllTileGroup(rows=rws, idx=idx, deg=deg, w=wgt),)
 
 
 def _scatter_combine(out: jnp.ndarray, rows: jnp.ndarray, vals: jnp.ndarray,
@@ -425,7 +445,7 @@ def fused_edge_map(
     extra_tiles: Tuple[EllTileGroup, ...] = (),
     row_tile: int = 64,
     width_tile: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Full fused edge map: per-group kernels + O(V) combine.
 
@@ -468,7 +488,11 @@ def fused_edge_map(
             width_tile=_tile_of(w_pad, width_tile),
             interpret=interpret,
         )
-        out = out.at[t.rows].set(y[: t.num_rows])
+        # rows are ascending and disjoint: a sorted scatter, which the TPU
+        # compiler lowers in ~1 s where an unsorted one takes ~25 s at 1M+
+        # rows
+        out = out.at[t.rows].set(y[: t.num_rows], indices_are_sorted=True,
+                                 unique_indices=True)
     for t in extra_tiles:
         r_pad, w_pad = t.idx.shape
         y = ell_edge_map_pallas(
@@ -509,7 +533,7 @@ def fused_edge_map_bytes(
     for t in tuple(tiles) + tuple(extra_tiles):
         r_pad, w_pad = t.idx.shape
         total += edge_map_tile_bytes(
-            r_pad, w_pad, num_vertices,
+            r_pad, w_pad,
             weighted=use_weights and t.w is not None,
             frontier=frontier,
             alive=t.alive is not None,

@@ -16,9 +16,13 @@ D multiple of 128 (lanes), T multiple of 8 (sublanes).
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..mode import interpret_mode
 
 __all__ = ["hot_gather_pallas"]
 
@@ -38,7 +42,7 @@ def hot_gather_pallas(
     hot_table: jnp.ndarray,
     *,
     token_tile: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """(T,) ids, (H, D) hot table -> (T, D); cold ids produce zero rows."""
     t = ids.shape[0]
@@ -54,5 +58,5 @@ def hot_gather_pallas(
         ],
         out_specs=pl.BlockSpec((token_tile, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, d), hot_table.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(ids, hot_table)

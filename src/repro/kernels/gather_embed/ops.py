@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +26,7 @@ def split_gather(
     ids: jnp.ndarray,
     *,
     token_tile: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Gather from the logical table concat([hot, cold]) with the hot path
     served by the VMEM-resident Pallas kernel."""

@@ -15,9 +15,13 @@ tile is lane-aligned (multiple of 128).
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..mode import interpret_mode
 
 __all__ = ["hist_bin_pallas"]
 
@@ -51,7 +55,7 @@ def hist_bin_pallas(
     boundaries: jnp.ndarray,
     *,
     tile: int = 4096,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Returns (groups (V,), histogram (K,)). V must be a multiple of ``tile``
     (ops.py pads)."""
@@ -74,5 +78,5 @@ def hist_bin_pallas(
             jax.ShapeDtypeStruct((v,), jnp.int32),
             jax.ShapeDtypeStruct((k,), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(degrees, boundaries)

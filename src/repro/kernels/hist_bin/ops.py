@@ -9,6 +9,7 @@ program, not the tile kernel.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +45,7 @@ def dbg_bin(
     boundaries: jnp.ndarray,
     *,
     tile: int = 4096,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Full DBG (Listing 1) on device. Returns (mapping, groups, histogram)."""
     v = degrees.shape[0]

@@ -13,6 +13,7 @@ graph (tests), like every kernel family in this package.
 from __future__ import annotations
 
 from functools import partial
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -62,7 +63,7 @@ def pack_spmv(
     *,
     row_tile: int = 64,
     width_tile: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """y (V,) = pull-mode SpMV over the packed pull adjacency.
 

@@ -16,9 +16,13 @@ iota offset by the width-tile coordinate.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from ..mode import interpret_mode
 
 __all__ = ["hot_spmv_pallas"]
 
@@ -63,7 +67,7 @@ def hot_spmv_pallas(
     *,
     row_tile: int = 64,
     width_tile: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jnp.ndarray:
     """y (R,) = rowsum over valid slots of x[idx] (* w).
 
@@ -85,7 +89,7 @@ def hot_spmv_pallas(
             in_specs=[x_spec, tile_spec, row_spec],
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((r,), x.dtype),
-            interpret=interpret,
+            interpret=interpret_mode(interpret),
         )(x, idx, deg)
     return pl.pallas_call(
         _kernel_weighted,
@@ -93,5 +97,5 @@ def hot_spmv_pallas(
         in_specs=[x_spec, tile_spec, row_spec, tile_spec],
         out_specs=row_spec,
         out_shape=jax.ShapeDtypeStruct((r,), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, idx, deg, w)

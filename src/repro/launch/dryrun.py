@@ -116,7 +116,8 @@ def lower_cell(cfg: ArchConfig, cell: ShapeCell, mesh,
         opt_sds = _with_shardings(o_shapes, o_specs, mesh)
         fn = train_step_mod.make_train_step(cfg, oc)
         jitted = jax.jit(fn, donate_argnums=(0, 1))
-        with mesh, activation_sharding(tuple(mesh.axis_names), dict(mesh.shape)):
+        with jax.set_mesh(mesh), activation_sharding(
+                tuple(mesh.axis_names), dict(mesh.shape)):
             lowered = jitted.lower(params_sds, opt_sds, batch)
     elif cell.kind == "prefill":
         p_shapes = jax.eval_shape(
@@ -133,7 +134,8 @@ def lower_cell(cfg: ArchConfig, cell: ShapeCell, mesh,
             return logits[:, -1]
 
         jitted = jax.jit(prefill_fn)
-        with mesh, activation_sharding(tuple(mesh.axis_names), dict(mesh.shape)):
+        with jax.set_mesh(mesh), activation_sharding(
+                tuple(mesh.axis_names), dict(mesh.shape)):
             lowered = jitted.lower(params_sds, batch)
     else:  # decode
         p_shapes = jax.eval_shape(
@@ -154,7 +156,8 @@ def lower_cell(cfg: ArchConfig, cell: ShapeCell, mesh,
             return logits, cache
 
         jitted = jax.jit(decode_fn, donate_argnums=(1,))
-        with mesh, activation_sharding(tuple(mesh.axis_names), dict(mesh.shape)):
+        with jax.set_mesh(mesh), activation_sharding(
+                tuple(mesh.axis_names), dict(mesh.shape)):
             lowered = jitted.lower(params_sds, cache_sds, batch)
 
     compiled = lowered.compile()
@@ -216,7 +219,9 @@ def run(arch_ids, shape_names, meshes, out_path: str,
         if mesh_kind.startswith("pods"):
             import jax as _jax
             n_pods = int(mesh_kind[4:])
-            mesh = _jax.make_mesh((n_pods, 16, 16), ("pod", "data", "model"))
+            mesh = _jax.make_mesh(
+                (n_pods, 16, 16), ("pod", "data", "model"),
+                axis_types=(_jax.sharding.AxisType.Auto,) * 3)
         else:
             mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
         for arch in arch_ids:

@@ -134,7 +134,7 @@ class PackedBackend(FusedEdgeMaps):
     out_deg: jnp.ndarray  # (V,) int32
     row_tile: int = 64
     width_tile: int = 128
-    interpret: bool = True
+    interpret: Optional[bool] = None
     # build-time edge count, kept STATIC (pytree aux) so the observability
     # hook can read it under jax tracing, where array values are abstract
     num_edges: int = 0
@@ -180,7 +180,7 @@ class PackedBackend(FusedEdgeMaps):
 
 def packed_backend(pg: PackedGraph, *, row_tile: int = 64,
                    width_tile: int = 128,
-                   interpret: bool = True) -> PackedBackend:
+                   interpret: Optional[bool] = None) -> PackedBackend:
     """Build the ``apps.engine`` backend for a ``PackedGraph``.
 
     The pull direction becomes the fused-kernel tile set (hot slot tables

@@ -1,2 +1,2 @@
-from .analysis import (HW, HW_PROFILES, model_flops,  # noqa: F401
-                       parse_collective_bytes, roofline_terms)
+from .analysis import (DEVICE_KIND_PROFILES, HW, HW_PROFILES,  # noqa: F401
+                       model_flops, parse_collective_bytes, roofline_terms)

@@ -21,8 +21,8 @@ import os
 import re
 from typing import Dict, List, Optional, Tuple
 
-__all__ = ["HW", "HW_PROFILES", "parse_collective_bytes", "roofline_terms",
-           "model_flops"]
+__all__ = ["HW", "HW_PROFILES", "DEVICE_KIND_PROFILES",
+           "parse_collective_bytes", "roofline_terms", "model_flops"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,7 +30,8 @@ class HW:
     """A hardware roofline profile.
 
     Defaults are TPU v5e, but the profile is selectable: ``HW.profile()``
-    resolves the ``REPRO_HW_PROFILE`` env var (falling back to ``"v5e"``),
+    resolves the ``REPRO_HW_PROFILE`` env var, else the profile of the
+    device JAX runs on (``DEVICE_KIND_PROFILES``; an unknown kind raises),
     and ``repro.tune.cost`` routes every candidate price through it — under
     ``"cpu-interpret"`` (the Pallas interpreter on host CPU) the FLOP peak
     is infinite, so rankings degrade gracefully to modeled HBM bytes
@@ -52,10 +53,23 @@ class HW:
 
     @classmethod
     def profile(cls, name: Optional[str] = None) -> "HW":
-        """Look up a named profile; ``None`` reads ``REPRO_HW_PROFILE``
-        (default ``"v5e"``).  Unknown names raise with the known list."""
+        """Look up a named profile; ``None`` reads ``REPRO_HW_PROFILE``,
+        else picks by ``jax.devices()[0].device_kind``.  Unknown names and
+        device kinds raise with the known list: CPU callers name
+        ``"cpu-interpret"`` themselves."""
         if name is None:
-            name = os.environ.get("REPRO_HW_PROFILE", "v5e")
+            name = os.environ.get("REPRO_HW_PROFILE")
+        if name is None:
+            import jax
+
+            kind = jax.devices()[0].device_kind
+            try:
+                name = DEVICE_KIND_PROFILES[kind]
+            except KeyError:
+                raise ValueError(
+                    f"no hardware profile for device kind {kind!r}; known "
+                    f"kinds: {', '.join(sorted(DEVICE_KIND_PROFILES))} "
+                    "(name a profile, e.g. 'cpu-interpret')") from None
         try:
             return HW_PROFILES[name]
         except KeyError:
@@ -75,6 +89,10 @@ HW_PROFILES: Dict[str, HW] = {
     "cpu-interpret": HW(peak_flops=math.inf, hbm_bw=20e9, link_bw=math.inf,
                         dispatch_overhead=5e-5, name="cpu-interpret"),
 }
+
+#: ``device_kind`` as JAX reports it -> profile name (peaks: Google Cloud
+#: documentation, "TPU v5e").
+DEVICE_KIND_PROFILES: Dict[str, str] = {"TPU v5 lite": "v5e"}
 
 
 _DTYPE_BYTES = {

@@ -77,7 +77,7 @@ class ServeConfig:
     backend: str = "flat"
     row_tile: int = 64
     width_tile: int = 128
-    interpret: bool = True
+    interpret: Optional[bool] = None
     # pull/push switch point for batched SSSP; None = engine default or,
     # under backend="auto", whatever the resolved plan tuned
     density_threshold: Optional[float] = None

@@ -113,7 +113,7 @@ class DeltaGraph:
         self._out2in = self._match_directions(base)
         # key-sorted view of base out-edges for O(log E) deletion lookup
         key = self._base_src * np.int64(v) + self._base_dst
-        self._base_key_order = np.argsort(key, kind="stable")
+        self._base_key_order = csr._stable_argsort(key, v * v)
         self._base_key_sorted = key[self._base_key_order]
         # delta buffers (capacity-doubling append)
         cap = self._extra_capacity
@@ -145,9 +145,9 @@ class DeltaGraph:
         if base.out_csr.weights is not None:
             o = np.lexsort((base.out_csr.weights, out_src, out_dst))
             i = np.lexsort((base.in_csr.weights, in_src, in_dst))
-        else:
-            o = np.lexsort((out_src, out_dst))
-            i = np.lexsort((in_src, in_dst))
+        else:  # the same orders as lexsort((src, dst)), by radix passes
+            o = csr._stable_argsort(out_dst * v + out_src, v * v)
+            i = csr._stable_argsort(in_dst * v + in_src, v * v)
         out2in = np.empty(out_src.shape[0], dtype=np.int64)
         out2in[o] = i
         return out2in

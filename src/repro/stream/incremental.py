@@ -406,7 +406,7 @@ def edge_map_push_stream_fused(
     init: Optional[jnp.ndarray] = None,
     row_tile: int = 64,
     width_tile: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fused-kernel twin of :func:`edge_map_push_stream` (base + delta in one
     kernel family, no edge-parallel scatter).  Masked edges always take the
@@ -436,7 +436,7 @@ def edge_map_pull_stream_fused(
     use_weights: bool = False,
     row_tile: int = 64,
     width_tile: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fused-kernel twin of :func:`edge_map_pull_stream`.
 

@@ -56,7 +56,7 @@ class ShardedStreamService(StreamService):
                  *, n_shards: Optional[int] = None, mesh=None,
                  backend: str = "flat", policy: str = "replicate_hot",
                  num_hot_groups: int = 6, row_tile: int = 64,
-                 width_tile: int = 128, interpret: bool = True,
+                 width_tile: int = 128, interpret: Optional[bool] = None,
                  remap_headroom: float = 0.5,
                  shard_compact_threshold: Optional[float] = None):
         super().__init__(g, config)
@@ -67,7 +67,7 @@ class ShardedStreamService(StreamService):
             n = n_shards if n_shards is not None else len(devs)
             if n > len(devs):
                 raise ValueError(f"n_shards={n} > {len(devs)} devices")
-            mesh = jax.sharding.Mesh(np.array(devs[:n]), (dist_graph.AXIS,))
+            mesh = dist_graph.graph_mesh(n)
         self.mesh = mesh
         self.n_shards = int(np.prod(mesh.devices.shape))
         self._shard_kw = dict(

@@ -20,9 +20,10 @@ top-k shortlist:
 
 Bytes become seconds through :class:`repro.roofline.HW`: a memory term
 (modeled bytes / bandwidth), a compute term (~2 FLOPs per edge-lane), and
-a **dispatch term** — the number of Pallas grid steps each config's tile
-geometry implies (mirrored exactly from the kernels' ``grid=(r//rt,
-w//wt)``) times the profile's ``dispatch_overhead``.  On real hardware the
+a **dispatch term** — the number of (row tile × width tile) blocks each
+config's tile geometry implies times the profile's ``dispatch_overhead``
+(the kernel's own grid rounds row blocks up to 128 lanes, so this is the
+geometry's count, not the compiled grid's).  On real hardware the
 dispatch cost is ~0 and ranking is effectively by bytes; under
 ``cpu-interpret`` the interpreter's per-grid-step Python cost dominates
 small-graph wall clock, so pricing it is what makes the analytic shortlist
@@ -130,10 +131,13 @@ def ell_tile_geometry(
 ) -> List[Tuple[int, int, int]]:
     """``[(r_pad, w_pad, idx_itemsize)]`` of ``ell_tiles`` on this degree
     vector — the binning logic replayed without building a single plane:
-    deg-0 rows skipped, bins merged by padded width class."""
+    boundaries extended by ``width_bins``, deg-0 rows skipped, bins merged
+    by padded width class."""
     from ..core.reorder import _assign_groups
+    from ..kernels.edge_map.ops import width_bins
 
     deg = np.asarray(deg, np.int64)
+    boundaries = width_bins(boundaries, int(deg.max(initial=0)))
     grp = _assign_groups(deg, boundaries)
     by_width: Dict[int, int] = {}
     for k in range(len(boundaries)):
@@ -227,7 +231,7 @@ def _tile_set_bytes(geom: List[Tuple[int, int, int]], v: int,
     total = v * 4 * p.plane_k  # the O(V) combine write
     for r_pad, w_pad, itemsize in geom:
         total += edge_map_tile_bytes(
-            r_pad, w_pad, v,
+            r_pad, w_pad,
             weighted=p.use_weights and weighted,
             frontier=p.frontier, alive=False, init=push_init,
             idx_itemsize=itemsize, plane_k=p.plane_k,
@@ -272,8 +276,8 @@ def _config_geometry(gc: GraphCost, cfg: Dict) -> List[Tuple[int, int, int]]:
 
 
 def config_steps(gc: GraphCost, config: Dict, app: str = "pr") -> int:
-    """Pallas grid steps one iteration of ``app`` dispatches under
-    ``config`` — the kernels' ``grid = (r_pad // tile, w_pad // tile)``
+    """Tile blocks one iteration of ``app`` walks under ``config`` —
+    ``(r_pad // tile) * (w_pad // tile)``
     (with whole-dim blocks when a padded dim is smaller than its tile,
     mirroring ``ops._tile_of``) summed over tile groups and passes.  The
     flat backend is a fused XLA op chain — zero Pallas dispatches."""

@@ -43,7 +43,8 @@ batch = {"tokens": tokens, "labels": jnp.roll(tokens, -1, 1)}
 p1, o1, m1 = jax.jit(fn)(params, opt, batch)
 
 # 2x4 mesh
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 specs = shd.param_specs(params)
 specs = shd.enforce_divisibility(
     jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), params),
@@ -55,7 +56,7 @@ opt_s = {"m": jax.device_put(opt["m"], shard),
          "v": jax.device_put(opt["v"], shard),
          "step": opt["step"]}
 batch_s = jax.device_put(batch, NamedSharding(mesh, P("data", None)))
-with mesh, activation_sharding(("data", "model")):
+with jax.set_mesh(mesh), activation_sharding(("data", "model")):
     p2, o2, m2 = jax.jit(fn)(params_s, opt_s, batch_s)
 np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]), rtol=2e-5)
 for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
@@ -71,7 +72,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from repro.dist.pipeline import pipeline_apply
-mesh = jax.make_mesh((4,), ("pipe",))
+mesh = jax.make_mesh((4,), ("pipe",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 S, M, mb, d = 4, 6, 3, 16
 w = jax.random.normal(jax.random.PRNGKey(0), (S, d, d)) * 0.3
 x = jax.random.normal(jax.random.PRNGKey(1), (M, mb, d))

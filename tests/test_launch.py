@@ -52,7 +52,8 @@ def test_checkpoint_elastic_restore_other_mesh(tmp_path):
     params, opt = _tree(), {"step": jnp.int32(0)}
     ckpt_mod.save_checkpoint(str(tmp_path), 5, params, opt, 5,
                              jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), params)
     sho = jax.tree.map(lambda _: NamedSharding(mesh, P()), opt)
     out = ckpt_mod.restore_latest(str(tmp_path), params, opt,
@@ -99,12 +100,11 @@ import sys
 sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.train.compress import compressed_psum
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = jax.make_mesh((8,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,))
 x = jnp.asarray(np.random.default_rng(0).normal(size=(8, 128)).astype(np.float32))
-f = shard_map(lambda a: compressed_psum(a[0], "pod")[None],
-              mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
+f = jax.shard_map(lambda a: compressed_psum(a[0], "pod")[None],
+                  mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
 out = np.asarray(f(x))
 exact = x.mean(axis=0)
 for row in out:

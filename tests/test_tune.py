@@ -32,7 +32,7 @@ from repro.core.reorder import dbg_spec
 from repro.graph import csr
 from repro.kernels.edge_map.ops import ell_tiles, fused_edge_map_bytes
 from repro.obs.counters import flat_edge_map_bytes
-from repro.roofline import HW, HW_PROFILES
+from repro.roofline import DEVICE_KIND_PROFILES, HW, HW_PROFILES
 from repro.tune import cost as tcost
 from repro.tune import plan as tplan
 from repro.tune import search as tsearch
@@ -127,8 +127,13 @@ def test_validate_knobs():
 # roofline HW profiles (satellite)
 # ---------------------------------------------------------------------------
 
-def test_hw_profiles():
-    assert HW.profile().name == "v5e"
+def test_hw_profiles(monkeypatch):
+    assert HW.profile("v5e").name == "v5e"
+    # no name: the profile of the device JAX runs on; a CPU has none
+    monkeypatch.delenv("REPRO_HW_PROFILE", raising=False)
+    with pytest.raises(ValueError, match="no hardware profile"):
+        HW.profile()
+    assert HW_PROFILES[DEVICE_KIND_PROFILES["TPU v5 lite"]].name == "v5e"
     cpu = HW.profile("cpu-interpret")
     assert math.isinf(cpu.peak_flops)
     assert "v5e" in HW_PROFILES and "cpu-interpret" in HW_PROFILES
@@ -225,8 +230,10 @@ def test_flat_cost_is_the_counters_model(g):
 
 def test_rank_and_shortlist_keep_incumbent(g):
     gc = tcost.GraphCost.from_graph(g)
-    ranked = tcost.rank(gc, tspace.engine_space().grid(), app="pr")
-    assert ranked == tcost.rank(gc, tspace.engine_space().grid(), app="pr")
+    v5e = HW.profile("v5e")
+    ranked = tcost.rank(gc, tspace.engine_space().grid(), app="pr", hw=v5e)
+    assert ranked == tcost.rank(gc, tspace.engine_space().grid(), app="pr",
+                                hw=v5e)
     sl = tcost.shortlist(ranked, 3, must_include=tspace.DEFAULT_CONFIG)
     want = tcost.config_key(tspace.split_config(tspace.DEFAULT_CONFIG)[0])
     assert any(tcost.config_key(s.config) == want for s in sl)
@@ -414,7 +421,8 @@ def test_batched_sssp_density_threshold(gw):
 
 def test_sweep_audit_trail_and_feasibility(g):
     res = tsearch.sweep(g, app="pr", top_k=3, extras=2,
-                        reps_schedule=(1, 1), select="bytes")
+                        reps_schedule=(1, 1), select="bytes",
+                        hw=HW.profile("v5e"))
     gc = tcost.GraphCost.from_graph(g)
     budget = tcost.default_budget(gc, "pr")
     # selection is byte-feasible: never more modeled traffic than default
