@@ -41,6 +41,7 @@ import numpy as np
 from ..graph import csr
 from ..kernels.edge_map.edge_map import reduce_identity
 from ..obs import trace as obs_trace
+from ..obs.metrics import get_registry
 
 __all__ = [
     "GraphArrays",
@@ -83,6 +84,7 @@ class GraphArrays(NamedTuple):
         return int(self.in_src.shape[0])
 
 
+@obs_trace.traced("layout.graph_arrays", cat="setup")
 def _graph_arrays(g: csr.Graph) -> GraphArrays:
     """Host-side flattening of both CSR directions into GraphArrays.
 
@@ -369,8 +371,14 @@ def _build_ell(g: csr.Graph, *, row_tile: int = 64, width_tile: int = 128,
 
     in_deg = g.in_csr.degrees()
     spec = dbg_spec(max(1.0, float(in_deg.mean()) if in_deg.size else 1.0))
-    tiles = ell_tiles(g.in_csr, spec.boundaries,
-                      row_tile=row_tile, width_tile=width_tile)
+    with obs_trace.span("layout.ell_pack", "setup", edges=g.num_edges):
+        tiles = ell_tiles(g.in_csr, spec.boundaries,
+                          row_tile=row_tile, width_tile=width_tile)
+    # the slots each pass gathers, against the arcs they hold
+    registry = get_registry()
+    registry.gauge("layout.ell_slots").set(
+        sum(int(t.idx.size) for t in tiles))
+    registry.gauge("layout.arcs").set(g.num_edges)
     return EllBackend(_graph_arrays(g), tiles, row_tile=row_tile,
                       width_tile=width_tile, interpret=interpret)
 

@@ -28,6 +28,7 @@ def pagerank(
         _, it, err = state
         return jnp.logical_and(it < max_iters, err > tol)
 
+    @jax.named_scope("pagerank.iter")
     def body(state):
         rank, it, _ = state
         contrib = rank / out_deg

@@ -59,6 +59,7 @@ def sssp(ga, root: jnp.ndarray, *, max_iters: int = 0,
         _, frontier, it = state
         return jnp.logical_and(it < max_iters, jnp.any(frontier))
 
+    @jax.named_scope("sssp.iter")
     def body(state):
         dist, frontier, it = state
         if direction_optimizing:

@@ -23,6 +23,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..graph import csr
+from ..obs import trace as obs_trace
 
 __all__ = [
     "GroupingSpec",
@@ -283,9 +284,11 @@ def reorder_graph(
     'Degree Type used for Reordering'), build the mapping, relabel the CSR.
     The relabel (CSR rebuild) time is counted into ``seconds`` — the paper's
     reordering cost includes regenerating the CSR-like structure (§VIII-A)."""
-    degs = g.out_degrees() if degree_source == "out" else g.in_degrees()
-    res = TECHNIQUES[technique](degs, seed=seed)
+    with obs_trace.span("reorder.map", "setup", technique=technique):
+        degs = g.out_degrees() if degree_source == "out" else g.in_degrees()
+        res = TECHNIQUES[technique](degs, seed=seed)
     t0 = time.perf_counter()
-    g2 = csr.relabel(g, res.mapping, name=f"{g.name}+{technique}")
+    with obs_trace.span("reorder.relabel", "setup", technique=technique):
+        g2 = csr.relabel(g, res.mapping, name=f"{g.name}+{technique}")
     rebuild = time.perf_counter() - t0
     return g2, dataclasses.replace(res, seconds=res.seconds + rebuild)

@@ -665,8 +665,7 @@ def edge_map_pull_sharded(sg: ShardedGraphArrays, prop: jnp.ndarray, mesh, *,
             ranked, mesh=mesh,
             in_specs=(a, P(), a, a, a, a, a) + (a,) * len(dargs),
             out_specs=a, check_vma=False)
-        with obs_trace.span("dist.edge_map_pull", cat="dist",
-                            backend=backend, shards=d, reduce=reduce):
+        with jax.named_scope("dist.edge_map_pull"):
             out = fn(prop_blocks, hot_tab, sg.send_idx, sg.in_slot,
                      sg.in_dst_local, sg.in_w, sg.in_mask, *dargs)
         return out.reshape(-1)[: sg.num_vertices]
@@ -706,8 +705,7 @@ def edge_map_pull_sharded(sg: ShardedGraphArrays, prop: jnp.ndarray, mesh, *,
         ranked_ell, mesh=mesh,
         in_specs=(a, P(), a) + (a,) * (n_base + len(dtile_args)),
         out_specs=a, check_vma=False)
-    with obs_trace.span("dist.edge_map_pull", cat="dist",
-                        backend=backend, shards=d, reduce=reduce):
+    with jax.named_scope("dist.edge_map_pull"):
         out = fn(prop_blocks, hot_tab, sg.send_idx, *tile_args, *dtile_args)
     return out.reshape(-1)[: sg.num_vertices]
 
@@ -782,8 +780,7 @@ def edge_map_push_sharded(sg: ShardedGraphArrays, prop: jnp.ndarray, mesh, *,
         fn = jax.shard_map(ranked, mesh=mesh,
                            in_specs=(a, a, a, a, a) + (a,) * len(dargs),
                            out_specs=a, check_vma=False)
-        with obs_trace.span("dist.edge_map_push", cat="dist",
-                            backend=backend, shards=d, reduce=reduce):
+        with jax.named_scope("dist.edge_map_push"):
             out = fn(prop_blocks, sg.out_src_local, sg.out_dst, sg.out_w,
                      sg.out_mask, *dargs)
     else:
@@ -817,8 +814,7 @@ def edge_map_push_sharded(sg: ShardedGraphArrays, prop: jnp.ndarray, mesh, *,
         fn = jax.shard_map(ranked_ell, mesh=mesh,
                            in_specs=(a,) + (a,) * (n_base + len(dtile_args)),
                            out_specs=a, check_vma=False)
-        with obs_trace.span("dist.edge_map_push", cat="dist",
-                            backend=backend, shards=d, reduce=reduce):
+        with jax.named_scope("dist.edge_map_push"):
             out = fn(prop_blocks, *tile_args, *dtile_args)
 
     out = out.reshape(-1)[: sg.num_vertices]

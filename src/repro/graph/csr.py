@@ -15,6 +15,8 @@ from typing import Optional
 
 import numpy as np
 
+from ..obs import trace as obs_trace
+
 __all__ = ["CSR", "Graph", "from_edges", "ragged_offsets", "relabel",
            "validate"]
 
@@ -110,6 +112,7 @@ def _stable_argsort(key: np.ndarray, upper: int) -> np.ndarray:
             return order
 
 
+@obs_trace.traced("csr.sort", cat="setup")
 def _build_one_direction(
     key: np.ndarray, other: np.ndarray, num_vertices: int, weights: Optional[np.ndarray]
 ) -> CSR:
@@ -124,6 +127,7 @@ def _build_one_direction(
     return CSR(indptr=indptr, indices=indices, weights=w)
 
 
+@obs_trace.traced("csr.from_edges", cat="setup")
 def from_edges(
     src: np.ndarray,
     dst: np.ndarray,

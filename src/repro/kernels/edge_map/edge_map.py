@@ -236,7 +236,8 @@ def ell_edge_map_pallas(
     plane_spec = pl.BlockSpec((width_tile, rb), lambda i, j: (j, i))
     row_spec = pl.BlockSpec((k, rb), lambda i, j: (0, i))
 
-    args = [xt[:, idx_t], deg.reshape(1, r)]  # the gather runs in XLA
+    with jax.named_scope("edge_map.gather"):  # the gather runs in XLA
+        args = [xt[:, idx_t], deg.reshape(1, r)]
     in_specs = [pl.BlockSpec(block, lambda i, j: (0, j, i)),
                 pl.BlockSpec((1, rb), lambda i, j: (0, i))]
     if w is not None:
@@ -244,7 +245,8 @@ def ell_edge_map_pallas(
         in_specs.append(plane_spec)
     if frontier is not None:
         ft = frontier.T if frontier.ndim == 2 else frontier[None, :]
-        args.append(ft[:, idx_t])
+        with jax.named_scope("edge_map.gather"):
+            args.append(ft[:, idx_t])
         in_specs.append(pl.BlockSpec((ft.shape[0],) + block[1:],
                                      lambda i, j: (0, j, i)))
     if alive is not None:
@@ -276,5 +278,6 @@ def ell_edge_map_pallas(
         out_shape=jax.ShapeDtypeStruct((k, r), x.dtype),
         cost_estimate=cost,
         interpret=interpret_mode(interpret),
+        name="ell_edge_map",
     )(*args)
     return y.T if planar else y[0]

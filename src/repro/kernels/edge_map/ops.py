@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -468,47 +469,55 @@ def fused_edge_map(
     out_shape = (num_vertices,) + tuple(x.shape[1:])
     out = jnp.full(out_shape, identity, x.dtype) if init is None \
         else init.astype(x.dtype)
+    # each tile group's pass is named by its padded width (``ell.w<W>``),
+    # so a reader ties device time to the DBG width group by name
     for t in tiles:
         r_pad, w_pad = t.idx.shape
-        init_rows = None
-        if init is not None:
-            init_rows = jnp.full((r_pad,) + tuple(x.shape[1:]), identity,
-                                 x.dtype).at[: t.num_rows].set(out[t.rows])
-        y = ell_edge_map_pallas(
-            x, t.idx, t.deg,
-            reduce=reduce,
-            w=t.w if use_weights else None,
-            unit_weights=use_weights,
-            frontier=frontier,
-            alive=t.alive,
-            init_rows=init_rows,
-            neutral=neutral,
-            identity=identity,
-            row_tile=_tile_of(r_pad, row_tile),
-            width_tile=_tile_of(w_pad, width_tile),
-            interpret=interpret,
-        )
-        # rows are ascending and disjoint: a sorted scatter, which the TPU
-        # compiler lowers in ~1 s where an unsorted one takes ~25 s at 1M+
-        # rows
-        out = out.at[t.rows].set(y[: t.num_rows], indices_are_sorted=True,
-                                 unique_indices=True)
+        with jax.named_scope(f"ell.w{w_pad}"):
+            init_rows = None
+            if init is not None:
+                init_rows = jnp.full(
+                    (r_pad,) + tuple(x.shape[1:]), identity,
+                    x.dtype).at[: t.num_rows].set(out[t.rows])
+            y = ell_edge_map_pallas(
+                x, t.idx, t.deg,
+                reduce=reduce,
+                w=t.w if use_weights else None,
+                unit_weights=use_weights,
+                frontier=frontier,
+                alive=t.alive,
+                init_rows=init_rows,
+                neutral=neutral,
+                identity=identity,
+                row_tile=_tile_of(r_pad, row_tile),
+                width_tile=_tile_of(w_pad, width_tile),
+                interpret=interpret,
+            )
+            # rows are ascending and disjoint: a sorted scatter, which the
+            # TPU compiler lowers in ~1 s where an unsorted one takes ~25 s
+            # at 1M+ rows
+            with jax.named_scope("edge_map.combine"):
+                out = out.at[t.rows].set(y[: t.num_rows],
+                                         indices_are_sorted=True,
+                                         unique_indices=True)
     for t in extra_tiles:
         r_pad, w_pad = t.idx.shape
-        y = ell_edge_map_pallas(
-            x, t.idx, t.deg,
-            reduce=reduce,
-            w=t.w if use_weights else None,
-            unit_weights=use_weights,
-            frontier=frontier,
-            alive=t.alive,
-            neutral=neutral,
-            identity=identity,
-            row_tile=_tile_of(r_pad, row_tile),
-            width_tile=_tile_of(w_pad, width_tile),
-            interpret=interpret,
-        )
-        out = _scatter_combine(out, t.rows, y[: t.num_rows], reduce)
+        with jax.named_scope(f"ell.w{w_pad}"):
+            y = ell_edge_map_pallas(
+                x, t.idx, t.deg,
+                reduce=reduce,
+                w=t.w if use_weights else None,
+                unit_weights=use_weights,
+                frontier=frontier,
+                alive=t.alive,
+                neutral=neutral,
+                identity=identity,
+                row_tile=_tile_of(r_pad, row_tile),
+                width_tile=_tile_of(w_pad, width_tile),
+                interpret=interpret,
+            )
+            with jax.named_scope("edge_map.combine"):
+                out = _scatter_combine(out, t.rows, y[: t.num_rows], reduce)
     return out
 
 

@@ -60,7 +60,8 @@ class FlightRecorder(Tracer):
     and async events) and overrides only the emission path, so it can serve
     as the process-global tracer on its own or as the tee target of a full
     tracer.  ``export()`` / ``dump(path)`` return the ring contents, oldest
-    first, as a Chrome trace.
+    first, as a Chrome trace.  Its spans open no profiler annotation: the
+    always-on ring stays off the ``jax.profiler`` trace.
     """
 
     def __init__(self, capacity: int = 4096, clock=time.perf_counter_ns,
@@ -68,7 +69,7 @@ class FlightRecorder(Tracer):
                  wall_clock=time.monotonic):
         if capacity < 1:
             raise ValueError("capacity must be >= 1")
-        super().__init__(clock)
+        super().__init__(clock, annotate=False)
         self.capacity = int(capacity)
         self.dump_dir = dump_dir
         self.cooldown_s = float(cooldown_s)

@@ -2,13 +2,16 @@
 
 The measurement plane's clock: ``Tracer.span`` opens a nested, thread-safe
 span (context manager or decorator) on a monotone clock
-(``time.perf_counter_ns``); finished spans accumulate as Chrome trace
-events — the ``{"traceEvents": [...]}`` JSON that chrome://tracing and
-Perfetto load directly — with complete events (``ph == "X"``), microsecond
-timestamps, one track per thread.  Nesting is per-thread (a thread-local
-span stack tracks depth; Chrome infers the tree from timestamp containment
-within a ``tid``), so concurrent recorders never interleave each other's
-stacks.
+(``time.perf_counter_ns``) and, on a live tracer, a
+``jax.profiler.TraceAnnotation`` of the same name, so the span also lands
+on the ``/host:`` plane of any ``jax.profiler`` trace taken meanwhile, on
+the device ops' clock and nested as the spans nest; finished spans
+accumulate as Chrome trace events — the ``{"traceEvents": [...]}`` JSON
+that chrome://tracing and Perfetto load directly — with complete events
+(``ph == "X"``), microsecond timestamps, one track per thread.  Nesting
+is per-thread (a thread-local span stack tracks depth; Chrome infers the
+tree from timestamp containment within a ``tid``), so concurrent recorders
+never interleave each other's stacks.
 
 Tracing is OFF by default and costs one ``is``-check per call site when off:
 the module-level :func:`span` / :func:`instant` / :func:`counter` helpers
@@ -100,7 +103,8 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One open span; closing it appends a Chrome complete event."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_start", "_tid", "depth")
+    __slots__ = ("_tracer", "name", "cat", "args", "_start", "_tid", "depth",
+                 "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -111,6 +115,7 @@ class _Span:
         self._start = 0
         self._tid = 0
         self.depth = 0
+        self._annotation = None
 
     def add(self, **args) -> "_Span":
         """Attach result args discovered mid-span (shown in the trace UI)."""
@@ -125,12 +130,18 @@ class _Span:
         stack = tr._stack()
         self.depth = len(stack)
         stack.append(self)
+        if tr._annotate is not None:
+            self._annotation = tr._annotate(self.name)
+            self._annotation.__enter__()
         self._start = tr._clock()
         return self
 
     def __exit__(self, *exc):
         end = self._tracer._clock()
         tr = self._tracer
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
         stack = tr._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -165,11 +176,17 @@ class Tracer:
 
     All spans of all threads accumulate into one event list (appends are
     locked; open-span stacks are thread-local).  ``export()`` returns the
-    Chrome trace dict; ``save(path)`` writes it as JSON.
+    Chrome trace dict; ``save(path)`` writes it as JSON.  With ``annotate``
+    (the default) each span also opens a ``jax.profiler.TraceAnnotation``
+    of its name, which costs next to nothing while no profiler trace runs.
     """
 
-    def __init__(self, clock=time.perf_counter_ns):
+    def __init__(self, clock=time.perf_counter_ns, annotate: bool = True):
         self._clock = clock
+        self._annotate = None
+        if annotate:
+            from jax.profiler import TraceAnnotation
+            self._annotate = TraceAnnotation
         self._lock = threading.Lock()
         self._local = threading.local()
         self.pid = os.getpid()

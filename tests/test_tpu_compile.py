@@ -12,6 +12,7 @@ compiler.
 """
 import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -141,3 +142,26 @@ def test_sharded_ell_pull_compiles(topo):
 
     assert _compiled_has_kernel(pull, _sds((V,), jnp.float32, rep),
                                 sg0.send_idx, sg0.hot_ids, tiles)
+
+
+def test_kernel_and_gather_carry_their_names(one_chip):
+    """On the TPU the kernel's instruction is named ``ell_edge_map`` and the
+    fusion that gathers ``x[idx]`` carries the ``edge_map.gather`` scope
+    (under its width group's ``ell.w<W>``) in its metadata: what the
+    benchmark's trace readers key on."""
+    tiles = _tiles([(64, 256), (4096, 8)], one_chip)
+
+    def pull(tiles, x):
+        return fused_edge_map(tiles, x, V, interpret=False)
+
+    text = jax.jit(pull).lower(
+        tiles, _sds((V,), jnp.float32, one_chip)).compile().as_text()
+    ops = re.findall(r'%([\w.-]+) = (\S+) ([\w-]+)\(.*?'
+                     r'metadata=\{[^}]*?op_name="([^"]*)"', text)
+    kernels = [n for n, _, code, _ in ops if code == "custom-call"
+               and n.startswith("ell_edge_map")]
+    assert len(kernels) == 2
+    for w in (256, 8):
+        gathers = [n for n, _, code, p in ops if code == "fusion"
+                   and p.endswith(f"ell.w{w}/edge_map.gather/gather")]
+        assert gathers, w
