@@ -62,14 +62,14 @@ def main():
     # ----- backend selection: the same apps over the fused edge-map backend --
     # to_arrays(g) is the flat oracle; to_arrays(g, backend="ell") routes every
     # edge_map_pull/push through the fused Pallas kernels (kernels.edge_map):
-    # gather + weight-add + frontier-mask + reduce in ONE pass over DBG-ELL
+    # gather + weight-add + frontier-mask + reduce in ONE pass over ELL
     # tiles, push included (a push with a reduction is the transposed pull).
     print("\nedge-map backends (repro.apps.engine):")
     ga_ell = to_arrays(g2, backend="ell")
     gaw_ell = to_arrays(gw2, backend="ell")
     from repro.kernels.edge_map.ops import fused_edge_map_bytes
     slots = sum(int(np.prod(t.idx.shape)) for t in ga_ell.in_tiles)
-    print(f"  EllBackend: {len(ga_ell.in_tiles)} DBG-ELL groups, "
+    print(f"  EllBackend: {len(ga_ell.in_tiles)} ELL width classes, "
           f"{slots/g2.num_edges:.2f} slots/edge, fused pull "
           f"{fused_edge_map_bytes(ga_ell.in_tiles, g2.num_vertices)/1e6:.1f} "
           f"MB/iter (single pass)")

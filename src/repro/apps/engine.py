@@ -18,10 +18,11 @@ Two backends implement the primitives behind one protocol:
     weight add → frontier mask → segment reduce / scatter), 3-4 separate O(E)
     HBM passes.  Kept as the oracle: every app must agree with it.
   * ``EllBackend`` — the ``kernels.edge_map`` Pallas family: the whole edge
-    map fused into one pass over per-DBG-group ELL tiles (the layouts the
-    paper's grouping argues for).  Push needs no scatter at all — a push with
-    a reduction into destinations is the pull of the transposed direction, so
-    the same in-direction tiles serve both primitives.  min/max reductions
+    map fused into one pass over ELL tiles, one set per width class chosen
+    from the degree histogram (DBG's ids decide what each gather reads, not
+    how rows pad).  Push needs no scatter at all — a push with a reduction
+    into destinations is the pull of the transposed direction, so the same
+    in-direction tiles serve both primitives.  min/max reductions
     are bit-identical to flat; sum differs only in fp association (~1e-6).
 
 Apps are written against the dispatching ``edge_map_pull``/``edge_map_push``
@@ -330,7 +331,7 @@ class FusedEdgeMaps:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass(frozen=True)
 class EllBackend(_Delegate, FusedEdgeMaps):
-    """Fused Pallas edge maps over per-DBG-group ELL tiles (kernels.edge_map).
+    """Fused Pallas edge maps over ELL width-class tiles (kernels.edge_map).
 
     The flat arrays stay on board for the operations outside the fused hot
     path (BC's backward dependency sweep, ``frontier_density``, dist
@@ -366,18 +367,16 @@ def _build_flat(g: csr.Graph, **_):
 
 def _build_ell(g: csr.Graph, *, row_tile: int = 64, width_tile: int = 128,
                interpret: Optional[bool] = None):
-    from ..core.reorder import dbg_spec
     from ..kernels.edge_map.ops import ell_tiles
 
-    in_deg = g.in_csr.degrees()
-    spec = dbg_spec(max(1.0, float(in_deg.mean()) if in_deg.size else 1.0))
     with obs_trace.span("layout.ell_pack", "setup", edges=g.num_edges):
-        tiles = ell_tiles(g.in_csr, spec.boundaries,
-                          row_tile=row_tile, width_tile=width_tile)
-    # the slots each pass gathers, against the arcs they hold
+        tiles = ell_tiles(g.in_csr, row_tile=row_tile, width_tile=width_tile)
+    # the slots each pass gathers, against the arcs they hold, and the
+    # width classes (gathers per pass) they fall into
     registry = get_registry()
     registry.gauge("layout.ell_slots").set(
         sum(int(t.idx.size) for t in tiles))
+    registry.gauge("layout.ell_classes").set(len(tiles))
     registry.gauge("layout.arcs").set(g.num_edges)
     return EllBackend(_graph_arrays(g), tiles, row_tile=row_tile,
                       width_tile=width_tile, interpret=interpret)
@@ -419,7 +418,7 @@ def _build_auto(g: csr.Graph, *, app: Optional[str] = None, plan=None,
 #: ``repro.tune.space.BACKEND_KNOBS`` — keep the two tables in sync.
 BACKENDS: Dict[str, Callable] = {
     "flat": _build_flat,      # edge-parallel oracle (gather/segment/scatter)
-    "ell": _build_ell,        # fused Pallas kernels over DBG-ELL tiles
+    "ell": _build_ell,        # fused Pallas kernels over ELL tiles
     "packed": _build_packed,  # fused kernels straight over pack.PackedGraph
     "arrays": _build_arrays,  # raw GraphArrays (the dist/stream substrate)
     "auto": _build_auto,      # plan-resolved (repro.tune) concrete backend
@@ -446,7 +445,7 @@ def to_arrays(
     """Build an edge-map backend for ``g`` (resolved through ``BACKENDS``).
 
     ``backend="flat"`` (default) keeps the edge-parallel oracle path;
-    ``"ell"`` packs the in-direction into per-DBG-group ELL tiles and routes
+    ``"ell"`` packs the in-direction into ELL width-class tiles and routes
     every edge map through the fused Pallas kernels (``row_tile`` /
     ``width_tile`` / ``interpret``); ``"packed"`` packs ``g`` into hot/cold
     segmented storage (``repro.pack``, plus ``slot_align`` / ``hot_groups``)

@@ -31,7 +31,7 @@ same ``apps.engine.BACKENDS`` name table as the single-device engine:
 
 * ``"flat"`` — the edge-parallel oracle above (gather → mask → segment
   reduce / scatter), 3-4 separate O(E_shard) HBM passes per device;
-* ``"ell"`` — each shard's edge segment packed into DBG-ELL tiles
+* ``"ell"`` — each shard's edge segment packed into ELL width-class tiles
   (``kernels.edge_map.ops.ell_tiles_sharded``) whose lanes index the SAME
   concatenated value table, so the whole per-shard edge map is one fused
   Pallas pass; the collectives are identical.  Push needs no scatter — the
@@ -670,7 +670,7 @@ def edge_map_pull_sharded(sg: ShardedGraphArrays, prop: jnp.ndarray, mesh, *,
                      sg.in_dst_local, sg.in_w, sg.in_mask, *dargs)
         return out.reshape(-1)[: sg.num_vertices]
 
-    # fused per-shard DBG-ELL path: one kernel pass per width class over the
+    # fused per-shard ELL path: one kernel pass per width class over the
     # same gather table, then an O(v_blk) combine — no O(E) intermediates
     identity = reduce_identity(red)
     tile_args, meta = _flatten_tiles(sg.pull_tiles)

@@ -1,14 +1,16 @@
 """Host-side ELL tile packer + device driver for the fused edge map (K5).
 
-``ell_tiles`` packs ONE adjacency direction into per-DBG-group ELL tiles
-(the paper's Table IV column structure, same geometric-bin padding bound as
-``csr_spmv.ell_pack_groups``) with a per-row true-degree vector instead of a
-stored padding-weight plane, vectorized through ``csr.ragged_offsets``.
+``ell_tiles`` packs ONE adjacency direction into ELL width classes chosen
+from its degree histogram (``width_classes``: the fewest padded slots in at
+most ``MAX_WIDTH_CLASSES`` classes) with a per-row true-degree vector instead
+of a stored padding-weight plane, vectorized through ``csr.ragged_offsets``.
+DBG decides the vertex ids, and so the gather's indices; it does not decide
+the padding.
 
-``fused_edge_map`` is the device driver: one fused Pallas call per group,
-then an O(V) combine of per-group row results back into vertex space.  Rows
-are grouped by degree, so within the primary tile set every vertex appears in
-exactly one group and the combine is a plain set-scatter; ``extra_tiles``
+``fused_edge_map`` is the device driver: one fused Pallas call per class,
+then an O(V) combine of per-class row results back into vertex space.  Rows
+are classed by degree, so within the primary tile set every vertex appears in
+exactly one class and the combine is a plain set-scatter; ``extra_tiles``
 (the stream delta segment, whose destinations duplicate base rows) combine
 with the reduction's scatter-op instead.  Nothing here ever materializes an
 O(E) edge-parallel intermediate — that is the whole point.
@@ -33,12 +35,24 @@ __all__ = [
     "refresh_alive",
     "fused_edge_map",
     "fused_edge_map_bytes",
-    "width_bins",
+    "width_classes",
+    "MAX_WIDTH_CLASSES",
+    "CLASS_COST_SLOTS",
 ]
+
+#: The most ELL width classes one tile set is split into.  Each class costs
+#: one gather and one kernel pass per edge map, and one more shape to compile.
+MAX_WIDTH_CLASSES = 16
+
+#: What one more width class costs each edge map pass, in gathered slots:
+#: the class's own gather, kernel and combine launches.  On a TPU v5e a
+#: class's pass takes a fixed 1-2 us beside 7.2 ns per slot (per-operation
+#: device times of a traced scale-12 PageRank, ``tests/bench/data``).
+CLASS_COST_SLOTS = 256
 
 
 class EllTileGroup(NamedTuple):
-    """Device view of one degree-group's ELL tiles.
+    """Device view of one width class's ELL tiles.
 
     ``rows``  (R,)  int32 owning vertex ids (true, unpadded count); a
                     primary tile set (``ell_tiles``, the packed hot tables)
@@ -60,18 +74,18 @@ class EllTileGroup(NamedTuple):
         return int(self.rows.shape[0])
 
 
-def _round_up(n: int, m: int) -> int:
-    return ((n + m - 1) // m) * m
+def _pad_dims(n: np.ndarray, tile: int, fine: int = 8) -> np.ndarray:
+    """Adaptive padding: dims smaller than one tile pad to the fine (8-lane)
+    granularity and run as a single grid step; larger ones pad to full tiles.
+    Without this, a width-3 cold row would pad 42x to a 128-lane tile."""
+    n = np.maximum(np.asarray(n, np.int64), 1)
+    step = np.where(n >= tile, tile, fine)
+    return -(-n // step) * step
 
 
 def _pad_dim(n: int, tile: int, fine: int = 8) -> int:
-    """Adaptive padding: groups smaller than one tile pad to the fine (8-lane)
-    granularity and run as a single grid step; larger ones pad to full tiles.
-    Without this, a width-3 cold group would pad 42x to a 128-lane tile —
-    with it, per-group padding stays bounded by the geometric-bin argument."""
-    if n >= tile:
-        return _round_up(n, tile)
-    return _round_up(max(1, n), fine)
+    """:func:`_pad_dims` of one dim."""
+    return int(_pad_dims(n, tile, fine))
 
 
 def _tile_of(pad: int, tile: int) -> int:
@@ -85,20 +99,80 @@ def _id_dtype(num_vertices: int):
     return np.uint16 if num_vertices <= np.iinfo(np.uint16).max else np.int32
 
 
-def width_bins(boundaries: Sequence[int], max_degree: int) -> Tuple[int, ...]:
-    """DBG ``boundaries`` (descending) extended with doubling bins above the
-    hottest one, so no row is padded to more than twice its degree.
+def _fewest_slot_runs(widths: np.ndarray, counts: np.ndarray, row_tile: int,
+                      max_runs: int, run_cost: int = 0) -> Tuple[int, ...]:
+    """Split the ascending candidate ``widths`` (``counts`` rows need each)
+    into at most ``max_runs`` contiguous runs that cost the fewest slots.
 
-    DBG's top group is unbounded: on a Graph500 scale-22 graph it spans
-    in-degrees 512 to ~100K, and one width class for all of it would pad
-    ~25K rows to the widest (gigabytes of idx plane).  The geometric bins
-    below it already bound padding at 2x; this continues them upward."""
-    out = [int(b) for b in boundaries]
-    top = max(1, out[0])
-    while top * 2 <= max_degree:
-        top *= 2
-        out.insert(0, top)
-    return tuple(out)
+    A run becomes one class: its largest width times its padded row count
+    (``_pad_dims(rows, row_tile)``), exactly what the packer allocates, plus
+    ``run_cost`` slots for the class's own pass.  An exact dynamic program,
+    O(max_runs · n²) over the n candidates; among equally cheap splits it
+    takes the fewest runs.  Returns each run's width, ascending."""
+    n = int(widths.size)
+    k_max = min(max_runs, n)
+    csum = np.concatenate([[0], np.cumsum(counts, dtype=np.int64)])
+    # best[k, j]: the fewest slots for the first j candidates in k runs;
+    # start[k, j]: where the last of those runs starts
+    best = np.full((k_max + 1, n + 1), np.iinfo(np.int64).max // 4, np.int64)
+    best[0, 0] = 0
+    start = np.zeros((k_max + 1, n + 1), np.int64)
+    for j in range(1, n + 1):
+        # the last run holds candidates a .. j-1, for every a < j
+        last = int(widths[j - 1]) * _pad_dims(csum[j] - csum[:j], row_tile)
+        total = best[:-1, :j] + last
+        a = np.argmin(total, axis=1)
+        best[1:, j] = total[np.arange(k_max), a]
+        start[1:, j] = a
+    k = int(np.argmin(best[1:, n] + run_cost * np.arange(1, k_max + 1))) + 1
+    out, j = [], n
+    while k:
+        out.append(int(widths[j - 1]))
+        j, k = int(start[k, j]), k - 1
+    return tuple(out[::-1])
+
+
+def _class_widths(deg: np.ndarray, row_tile: int,
+                  width_tile: int) -> np.ndarray:
+    """The ascending class widths for rows of degrees ``deg`` (zero-degree
+    rows need none): each row of degree d > 0 needs at least the kernel
+    shape ``_pad_dims(d, width_tile)``, and the distinct needs, with their
+    row counts, are split by :func:`_fewest_slot_runs` into at most
+    ``MAX_WIDTH_CLASSES`` classes, each charged ``CLASS_COST_SLOTS``."""
+    deg = np.asarray(deg, np.int64)
+    hist = np.bincount(deg[deg > 0])
+    degrees = np.flatnonzero(hist)
+    if degrees.size == 0:
+        return np.zeros(0, np.int64)
+    needs, inverse = np.unique(_pad_dims(degrees, width_tile),
+                               return_inverse=True)
+    counts = np.bincount(inverse, weights=hist[degrees]).astype(np.int64)
+    return np.asarray(_fewest_slot_runs(needs, counts, row_tile,
+                                        MAX_WIDTH_CLASSES, CLASS_COST_SLOTS),
+                      np.int64)
+
+
+def width_classes(
+    deg: np.ndarray,
+    *,
+    row_tile: int = 64,
+    width_tile: int = 128,
+) -> Tuple[Tuple[int, np.ndarray], ...]:
+    """The ELL width classes of rows with degrees ``deg``: ``(w_pad, rows)``
+    per class of :func:`_class_widths`, widest first, rows ascending (the
+    sorted combine needs it).
+
+    Each row goes to the narrowest class at least as wide as its degree.
+    Zero-degree rows are in no class: they take the reduction identity in
+    the combine, and where the CSR covers only a row subset
+    (``repro.pack``'s cold segment) a zero-degree row may be owned by
+    another tile set, which a set-combine must not clobber."""
+    deg = np.asarray(deg, np.int64)
+    widths = _class_widths(deg, row_tile, width_tile)
+    rows = np.flatnonzero(deg > 0)
+    cls = np.searchsorted(widths, deg[rows])  # narrowest class >= degree
+    return tuple((int(widths[k]), rows[cls == k])
+                 for k in range(widths.size - 1, -1, -1))
 
 
 def _slot_coords(degs: np.ndarray):
@@ -160,50 +234,22 @@ def refresh_alive(
 
 def ell_tiles(
     adj: csr_mod.CSR,
-    boundaries: Sequence[int],
     *,
     row_tile: int = 64,
     width_tile: int = 128,
     alive_edges: Optional[np.ndarray] = None,
 ) -> Tuple[EllTileGroup, ...]:
-    """Pack one CSR direction into per-DBG-group ELL tiles (host, one pass).
+    """Pack one CSR direction into ELL tiles, one set per width class of
+    :func:`width_classes` (host, one pass), widest class first.
 
-    Rows (owning vertices) are binned by THEIR degree into the geometric
-    ``boundaries`` ranges, so each group's width is at most ~2x its smallest
-    member — the paper's binning doubling as the TPU occupancy structure.
-    Zero-degree rows are skipped (they take the reduction identity in the
-    combine).  ``alive_edges`` is an optional per-edge bool in storage order
-    (the stream base tombstone mask).
+    ``alive_edges`` is an optional per-edge bool in storage order (the
+    stream base tombstone mask).
     """
-    from ...core.reorder import _assign_groups
-
     deg_all = adj.degrees()
-    boundaries = width_bins(boundaries, int(deg_all.max(initial=0)))
-    grp = _assign_groups(deg_all, boundaries)
-    # bin by DBG group, then MERGE bins that land in the same padded width
-    # class: the deg mask already handles intra-group variance, and one tile
-    # set per width class means one gather + kernel launch per class instead
-    # of one per bin (several cold bins share the fine 8/16-lane widths).
-    by_width = {}
-    for k in range(len(boundaries)):
-        # zero-degree rows really are skipped (they take the reduction
-        # identity in the combine) — essential when the CSR covers only a
-        # row SUBSET (repro.pack's cold segment): a deg-0 row here may be
-        # owned by another tile set, and a set-combine row must not clobber
-        # it with the identity.
-        rows = np.where((grp == k) & (deg_all > 0))[0]
-        if rows.size == 0:
-            continue
-        degs = deg_all[rows].astype(np.int64)
-        wmax = int(degs.max())
-        w_pad = _pad_dim(wmax, width_tile)
-        by_width.setdefault(w_pad, []).append((rows, degs))
     out = []
-    for w_pad, parts in by_width.items():  # insertion order: hottest first
-        rows = np.concatenate([p[0] for p in parts])
-        degs = np.concatenate([p[1] for p in parts])
-        order = np.argsort(rows, kind="stable")  # ascending: sorted combine
-        rows, degs = rows[order], degs[order]
+    for w_pad, rows in width_classes(deg_all, row_tile=row_tile,
+                                     width_tile=width_tile):
+        degs = deg_all[rows].astype(np.int64)
         r_pad = _pad_dim(rows.size, row_tile)
         idx, w, alive = _fill_planes(adj, rows, degs, r_pad, w_pad,
                                      alive_edges)
@@ -223,7 +269,6 @@ def ell_tiles_sharded(
     shard_edges: Sequence[Tuple[np.ndarray, np.ndarray, Optional[np.ndarray]]],
     *,
     id_upper: int,
-    boundaries: Optional[Sequence[int]] = None,
     row_tile: int = 64,
     width_tile: int = 128,
     with_positions: bool = False,
@@ -238,11 +283,11 @@ def ell_tiles_sharded(
     (``dist.graph.place_shards``).  They carry a leading shard dim on every
     plane — ``rows (D, R_pad)``, ``idx (D, R_pad, W_pad)``,
     ``deg (D, R_pad)``, optional ``w`` — because ``shard_map`` needs one
-    static tile geometry per device: rows are binned by their (shard-local)
-    degree into the shared geometric ``boundaries``, each bin's padded width
-    is taken from its max over ALL shards, same-width bins merge into one
-    class (the ``ell_tiles`` idiom), and each class's row dim pads to the max
-    shard population.  Padding rows have ``deg == 0`` and ``rows == 0``, so a
+    static tile geometry per device: the width classes are chosen once from
+    the degrees of every shard's rows pooled (``_class_widths``, the
+    ``ell_tiles`` rule), each row goes to the narrowest class at least its
+    (shard-local) degree, and each class's row dim pads to the max shard
+    population.  Padding rows have ``deg == 0`` and ``rows == 0``, so a
     scatter-combine into an identity-initialized accumulator ignores them.
 
     ``with_positions=True`` additionally returns, per shard, an ``(E_i, 3)``
@@ -253,8 +298,6 @@ def ell_tiles_sharded(
     layout can later kill individual lanes in place (the sharded counterpart
     of the stream base's ``refresh_alive`` bitplanes).
     """
-    from ...core.reorder import _assign_groups, dbg_spec
-
     d = len(shard_edges)
     per = []  # (urows, degs, starts, cols_sorted, w_sorted, order)
     for rows, cols, w in shard_edges:
@@ -265,32 +308,18 @@ def ell_tiles_sharded(
                     None if w is None else w[order], order))
     pooled = (np.concatenate([p[1] for p in per])
               if any(p[1].size for p in per) else np.zeros(0, np.int64))
-    if boundaries is None:
-        mean = max(1.0, float(pooled.mean()) if pooled.size else 1.0)
-        boundaries = dbg_spec(mean).boundaries
-    boundaries = width_bins(boundaries, int(pooled.max(initial=0)))
-    nb = len(boundaries)
-    shard_bins = [_assign_groups(p[1], boundaries) for p in per]
-    bin_wmax = np.zeros(nb, np.int64)
-    for (_, degs, *_), grp in zip(per, shard_bins):
-        if degs.size:
-            np.maximum.at(bin_wmax, grp, degs)
-    by_width: dict = {}  # w_pad -> [bin ids], hottest bin first
-    for k in range(nb):
-        if bin_wmax[k] == 0:
-            continue
-        by_width.setdefault(_pad_dim(int(bin_wmax[k]), width_tile),
-                            []).append(k)
+    widths = _class_widths(pooled, row_tile, width_tile)
+    # narrowest class >= degree; rows stay ascending within a class
+    shard_cls = [np.searchsorted(widths, p[1]) for p in per]
 
     weighted = any(p[4] is not None for p in per)
     id_dtype = _id_dtype(id_upper)
     groups = []
     positions = [np.full((rows.shape[0], 3), -1, np.int32)
                  for rows, _, _ in shard_edges]
-    for ci, (w_pad, bins) in enumerate(by_width.items()):
-        sels = [np.concatenate([np.flatnonzero(g == k) for k in bins])
-                if g.size else np.zeros(0, np.int64)
-                for g in shard_bins]
+    for ci, k in enumerate(range(widths.size - 1, -1, -1)):  # widest first
+        w_pad = int(widths[k])
+        sels = [np.flatnonzero(c == k) for c in shard_cls]
         r_pad = _pad_dim(max(int(s.size) for s in sels), row_tile)
         idx = np.zeros((d, r_pad, w_pad), id_dtype)
         deg = np.zeros((d, r_pad), np.int32)
@@ -470,7 +499,7 @@ def fused_edge_map(
     out = jnp.full(out_shape, identity, x.dtype) if init is None \
         else init.astype(x.dtype)
     # each tile group's pass is named by its padded width (``ell.w<W>``),
-    # so a reader ties device time to the DBG width group by name
+    # so a reader ties device time to the width class by name
     for t in tiles:
         r_pad, w_pad = t.idx.shape
         with jax.named_scope(f"ell.w{w_pad}"):

@@ -184,14 +184,15 @@ def packed_backend(pg: PackedGraph, *, row_tile: int = 64,
     """Build the ``apps.engine`` backend for a ``PackedGraph``.
 
     The pull direction becomes the fused-kernel tile set (hot slot tables
-    wrapped in place + cold rows decoded once, binned by the layout's own
-    boundaries); the push primitive rides the SAME tiles (transposed-pull
-    trick), so only BC's backward sweep touches the out direction.
+    wrapped in place + cold rows decoded once, in the width classes of
+    their degree histogram); the push primitive rides the SAME tiles
+    (transposed-pull trick), so only BC's backward sweep touches the out
+    direction.
     """
     in_adj = pg.in_adj
     tiles = _hot_tiles(in_adj, row_tile, width_tile)
-    tiles += ell_tiles(in_adj.cold_csr(), in_adj.boundaries,
-                       row_tile=row_tile, width_tile=width_tile)
+    tiles += ell_tiles(in_adj.cold_csr(), row_tile=row_tile,
+                       width_tile=width_tile)
     return PackedBackend(
         in_tiles=tiles,
         out_hot=_hot_dev(pg.out_adj),

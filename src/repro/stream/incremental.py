@@ -351,15 +351,14 @@ def stream_push_tiles(dg: DeltaGraph, *, row_tile: int = 64,
                       width_tile: int = 128):
     """(base_tiles, delta_tiles) for the fused stream push.
 
-    The base in-direction is packed once per base snapshot into DBG-ELL
-    tiles — tombstones ride as an alive bitplane that is re-scattered (idx/w
+    The base in-direction is packed once per base snapshot into ELL width
+    classes — tombstones ride as an alive bitplane that is re-scattered (idx/w
     planes untouched) when the tombstone count moves, so a deletion does NOT
     force repacking between compactions.  The pending delta buffer (tiny,
     cold) becomes one dst-grouped ELL group per refresh and runs through the
     SAME fused kernel as a second segment, replacing the separate O(E_base)
     + O(D) scatters of ``edge_map_push_stream``.
     """
-    from ..core.reorder import dbg_spec
     from ..kernels.edge_map.ops import coo_tiles, ell_tiles, refresh_alive
 
     # Two-level cache, base compared by IDENTITY (Graph holds arrays; ==
@@ -371,10 +370,7 @@ def stream_push_tiles(dg: DeltaGraph, *, row_tile: int = 64,
     struct = getattr(dg, "_push_tile_struct", None)
     if (struct is None or struct[0] is not dg.base
             or struct[1] != (row_tile, width_tile)):
-        deg = in_csr.degrees()
-        spec = dbg_spec(max(1.0, float(deg.mean()) if deg.size else 1.0))
-        tiles = ell_tiles(in_csr, spec.boundaries, row_tile=row_tile,
-                          width_tile=width_tile)
+        tiles = ell_tiles(in_csr, row_tile=row_tile, width_tile=width_tile)
         struct = (dg.base, (row_tile, width_tile), tiles)
         dg._push_tile_struct = struct
         dg._push_tile_alive = None

@@ -9,9 +9,9 @@ top-k shortlist:
   * ``flat``   — :func:`repro.obs.counters.flat_edge_map_bytes` (the
     edge-parallel pass model ``benchmarks/edge_map_perf.py`` cross-checks
     against XLA's ``cost_analysis``);
-  * ``ell``    — per-width-class tile geometry recomputed from the degree
-    vector alone (mirroring ``kernels.edge_map.ops.ell_tiles`` binning
-    exactly — property-tested equal to ``fused_edge_map_bytes`` over the
+  * ``ell``    — per-width-class tile geometry from the degree vector alone
+    (the packer's own ``kernels.edge_map.ops.width_classes`` —
+    property-tested equal to ``fused_edge_map_bytes`` over the
     actually-built tiles), priced with ``edge_map_tile_bytes``;
   * ``packed`` — the hot/cold split of ``pack.layout.pack_adjacency``
     (stride quantization, sub-line power-of-two slots, hot-group
@@ -42,6 +42,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..kernels.edge_map.ops import _pad_dim, width_classes
 from ..roofline import HW
 from .space import DEFAULT_CONFIG, canonical, split_config
 
@@ -94,13 +95,6 @@ def _round_up(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m
 
 
-def _pad_dim(n: int, tile: int, fine: int = 8) -> int:
-    # mirrors kernels.edge_map.ops._pad_dim (adaptive fine-grain padding)
-    if n >= tile:
-        return _round_up(n, tile)
-    return _round_up(max(1, n), fine)
-
-
 def _ell_itemsize(num_vertices: int) -> int:
     # mirrors kernels.edge_map.ops._id_dtype
     return 2 if num_vertices <= np.iinfo(np.uint16).max else 4
@@ -123,32 +117,16 @@ def _dbg_boundaries(deg: np.ndarray) -> Tuple[int, ...]:
 
 def ell_tile_geometry(
     deg: np.ndarray,
-    boundaries: Sequence[int],
     *,
     row_tile: int,
     width_tile: int,
     itemsize: int,
 ) -> List[Tuple[int, int, int]]:
     """``[(r_pad, w_pad, idx_itemsize)]`` of ``ell_tiles`` on this degree
-    vector — the binning logic replayed without building a single plane:
-    boundaries extended by ``width_bins``, deg-0 rows skipped, bins merged
-    by padded width class."""
-    from ..core.reorder import _assign_groups
-    from ..kernels.edge_map.ops import width_bins
-
-    deg = np.asarray(deg, np.int64)
-    boundaries = width_bins(boundaries, int(deg.max(initial=0)))
-    grp = _assign_groups(deg, boundaries)
-    by_width: Dict[int, int] = {}
-    for k in range(len(boundaries)):
-        sel = (grp == k) & (deg > 0)
-        n = int(sel.sum())
-        if n == 0:
-            continue
-        w_pad = _pad_dim(int(deg[sel].max()), width_tile)
-        by_width[w_pad] = by_width.get(w_pad, 0) + n
-    return [(_pad_dim(n, row_tile), w_pad, itemsize)
-            for w_pad, n in by_width.items()]
+    vector: the packer's own ``width_classes``, without building a plane."""
+    return [(_pad_dim(rows.size, row_tile), w_pad, itemsize)
+            for w_pad, rows in width_classes(deg, row_tile=row_tile,
+                                             width_tile=width_tile)]
 
 
 def packed_tile_geometry(
@@ -194,7 +172,7 @@ def packed_tile_geometry(
 
     cold = deg.copy()
     cold[grp < hot_groups] = 0  # hot rows have degree 0 in the cold CSR
-    geom.extend(ell_tile_geometry(cold, boundaries, row_tile=row_tile,
+    geom.extend(ell_tile_geometry(cold, row_tile=row_tile,
                                   width_tile=width_tile,
                                   itemsize=_ell_itemsize(v)))
     return geom
@@ -265,8 +243,7 @@ def _config_geometry(gc: GraphCost, cfg: Dict) -> List[Tuple[int, int, int]]:
     width_tile = int(cfg.get("width_tile", 128))
     if backend == "ell":
         return ell_tile_geometry(
-            gc.in_deg, _dbg_boundaries(gc.in_deg),
-            row_tile=row_tile, width_tile=width_tile,
+            gc.in_deg, row_tile=row_tile, width_tile=width_tile,
             itemsize=_ell_itemsize(gc.num_vertices))
     return packed_tile_geometry(
         gc.in_deg, row_tile=row_tile, width_tile=width_tile,

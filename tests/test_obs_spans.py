@@ -13,6 +13,7 @@ import pytest
 from repro.apps import pagerank, to_arrays
 from repro.core import reorder
 from repro.graph import csr, generators
+from repro.kernels.edge_map.ops import MAX_WIDTH_CLASSES
 from repro.obs import flight as obs_flight
 from repro.obs import trace as obs_trace
 from repro.obs.metrics import reset_registry
@@ -130,6 +131,8 @@ def test_ell_layout_sets_its_slot_gauges():
         t.idx.shape[0] * t.idx.shape[1] for t in ga.in_tiles)
     assert snap["layout.arcs"] == g.num_edges
     assert snap["layout.ell_slots"] >= g.num_edges
+    assert snap["layout.ell_classes"] == len(ga.in_tiles)
+    assert 1 <= snap["layout.ell_classes"] <= MAX_WIDTH_CLASSES
     names = [e["name"] for e in tr.export()["traceEvents"]]
     assert names.count("layout.ell_pack") == 1
     assert names.count("layout.graph_arrays") == 1
@@ -137,6 +140,7 @@ def test_ell_layout_sets_its_slot_gauges():
     registry = reset_registry()
     to_arrays(g, backend="flat")
     assert "layout.ell_slots" not in registry.snapshot()
+    assert "layout.ell_classes" not in registry.snapshot()
 
 
 def test_compiled_pagerank_names_its_gather_by_scope():

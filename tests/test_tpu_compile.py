@@ -72,6 +72,20 @@ def test_ell_sum_pull_compiles(one_chip):
                                                   one_chip))
 
 
+@pytest.mark.parametrize("rows,width", [(500032, 24), (1602560, 40),
+                                        (320, 104)])
+def test_histogram_width_class_compiles(one_chip, rows, width):
+    """Width classes chosen from the degree histogram take any multiple of
+    8 below one width tile: scale-22 urand's largest class is w40."""
+    tiles = _tiles([(rows, width)], one_chip)
+
+    def pull(tiles, x):
+        return fused_edge_map(tiles, x, V, interpret=False)
+
+    assert _compiled_has_kernel(pull, tiles, _sds((V,), jnp.float32,
+                                                  one_chip))
+
+
 def test_ell_min_frontier_compiles(one_chip):
     """SSSP's push: min over unit weights, source frontier, init rows."""
     tiles = _tiles([(281344, 32)], one_chip)

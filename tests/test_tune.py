@@ -28,7 +28,6 @@ from hypothesis import given, settings, strategies as st
 
 from repro.apps import bc, pagerank, sssp, to_arrays
 from repro.apps.engine import BACKENDS, EllBackend, FlatBackend
-from repro.core.reorder import dbg_spec
 from repro.graph import csr
 from repro.kernels.edge_map.ops import ell_tiles, fused_edge_map_bytes
 from repro.obs.counters import flat_edge_map_bytes
@@ -188,10 +187,7 @@ def test_ell_cost_parity(row_tile, width_tile, seed):
     """The degree-vector geometry mirror prices EXACTLY what ell_tiles
     builds, for every pass shape the apps use."""
     gg = _rand_graph(200, 2400, seed)
-    deg = np.asarray(gg.in_degrees())
-    spec = dbg_spec(max(1.0, float(deg.mean()) if deg.size else 1.0))
-    tiles = ell_tiles(gg.in_csr, spec.boundaries,
-                      row_tile=row_tile, width_tile=width_tile)
+    tiles = ell_tiles(gg.in_csr, row_tile=row_tile, width_tile=width_tile)
     gc = tcost.GraphCost.from_graph(gg)
     cfg = {"backend": "ell", "row_tile": row_tile, "width_tile": width_tile}
     for profile in [p for ps in tcost.APP_PROFILES.values() for p in ps]:
